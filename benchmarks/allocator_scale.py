@@ -63,6 +63,7 @@ import argparse
 import json
 import platform
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,7 @@ from repro.api import (
 )
 from repro.core import EvalInputs, evaluate_batch, node_residuals
 from repro.engine import KubeAdaptor
+from repro.launch.cache import use_compile_cache
 from repro.serving import StreamEngine
 from repro.workflows import TaskSpec, WorkflowSpec
 
@@ -233,9 +235,9 @@ def report_engine(num_nodes: int, burst: int, repeats: int,
 
 # --------------------------------------------------------------- streaming
 
-def _stream_arrivals(count: int, mean_gap: float = 1.0):
+def _stream_arrivals(count: int, mean_gap: float = 1.0, seed: int = 0):
     """Poisson arrival stream of single-task workflows, time-sorted."""
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     out, t = [], 0.0
     for i in range(count):
         t += float(rng.exponential(mean_gap))
@@ -517,6 +519,7 @@ def main():
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write machine-readable results to PATH")
     args = ap.parse_args()
+    use_compile_cache(Path(__file__).resolve().parents[1])
     if args.nodes is not None and args.nodes <= 0:
         ap.error("--nodes must be positive")
     if args.burst <= 0:

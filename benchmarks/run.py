@@ -8,6 +8,7 @@ from __future__ import annotations
 import argparse
 import sys
 import traceback
+from pathlib import Path
 
 
 def main() -> None:
@@ -16,6 +17,9 @@ def main() -> None:
                     help="skip the full Table-2 matrix (CI mode)")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache(Path(__file__).resolve().parents[1])
     from benchmarks import (
         allocator_scale,
         fig1_lifecycle,

@@ -4,13 +4,13 @@ The sequential core of ``repro.core.allocator``: B task requests walk the
 carry (residual tiles, scalar totals, stamped mask, head-of-line flag) in
 admission order.  TPU-native blocking follows ``mamba_scan``: the grid's
 single (minor, sequential) dimension walks row chunks; the carry lives in
-VMEM/SMEM scratch for the whole burst (never returns to HBM), and each
-chunk streams only its row scalars and its ``[chunk, B]`` slab of the
-mid-burst correction tables.  Within a chunk the recurrence is a short
-``fori_loop``; every step is branchless — the Alg. 3 evaluator lattice,
-the placement key and both argmaxes (flat max + min-index, exact
-first-index tie semantics) are VPU element-wise ops over the resident
-``[num_blocks, LANE]`` residual tiles.
+VMEM/SMEM scratch for the whole burst (never returns to HBM), the row
+scalars sit whole in SMEM, and each chunk streams only its ``[chunk, B]``
+slab of the mid-burst correction tables.  Within a chunk the recurrence
+is a short ``fori_loop``; every step is branchless — the Alg. 3
+evaluator lattice, the placement key and both argmaxes (flat max +
+min-index, exact first-index tie semantics) are VPU element-wise ops
+over the resident ``[num_blocks, LANE]`` residual tiles.
 
 Decisions are bit-for-bit identical to ``ref.alloc_scan_ref``: max /
 compare / select are exact, and all rounding arithmetic (demand
@@ -31,6 +31,7 @@ from repro.core.placement import placement_key
 from repro.kernels.alloc_scan.ref import LANE
 
 _BIG_I32 = 2**31 - 1  # python int: traced literals may not be captured
+_SLAB_ELEMS = 1 << 19  # f32 elements in one buffered correction-table slab
 
 
 def _flat_argmax(x: jax.Array, flat_idx: jax.Array):
@@ -90,14 +91,14 @@ def _scan_kernel(
         rid = si * chunk + t
         rc2, rm2 = rc_s[...], rm_s[...]
         stamped = stamped_s[0]
-        cpu, mem = cpu_ref[t], mem_ref[t]
-        self_slot = self_ref[t]
-        pending = pending_ref[t] != 0
+        cpu, mem = cpu_ref[rid], mem_ref[rid]
+        self_slot = self_ref[rid]
+        pending = pending_ref[rid] != 0
         blocked = blocked_s[0] != 0
-        attempt = (attempt_ref[t] != 0) & ~(pending & blocked)
+        attempt = (attempt_ref[rid] != 0) & ~(pending & blocked)
         if mode == "aras":
-            req_c = base_c_ref[t] + jnp.sum(dc_ref[t] * stamped)
-            req_m = base_m_ref[t] + jnp.sum(dm_ref[t] * stamped)
+            req_c = base_c_ref[rid] + jnp.sum(dc_ref[t] * stamped)
+            req_m = base_m_ref[rid] + jnp.sum(dm_ref[t] * stamped)
             re_max_cpu, imax = _flat_argmax(rc2, flat_idx)
             re_max_mem = _pick(rm2, flat_idx, imax)
             # Federation-wide totals: same static left-fold as the ref's
@@ -121,7 +122,8 @@ def _scan_kernel(
             )
             alloc_c, alloc_m = result.cpu, result.mem
             scenario = result.scenario
-            ok = (alloc_c >= min_cpu_ref[t]) & (alloc_m >= min_mem_ref[t] + beta)
+            ok = ((alloc_c >= min_cpu_ref[rid])
+                  & (alloc_m >= min_mem_ref[rid] + beta))
         else:  # fcfs
             alloc_c, alloc_m = cpu, mem
             scenario = jnp.int32(FCFS_SCENARIO)
@@ -149,12 +151,12 @@ def _scan_kernel(
         blocked_s[0] = (blocked | (pending & attempt & ~(ok & fits_any))
                         ).astype(jnp.int32)
 
-        alloc_c_ref[t] = alloc_c
-        alloc_m_ref[t] = alloc_m
-        node_ref[t] = jnp.where(fits_any, node, jnp.int32(-1))
-        accept_ref[t] = accept.astype(jnp.int32)
-        attempted_ref[t] = attempt.astype(jnp.int32)
-        scenario_ref[t] = scenario
+        alloc_c_ref[rid] = alloc_c
+        alloc_m_ref[rid] = alloc_m
+        node_ref[rid] = jnp.where(fits_any, node, jnp.int32(-1))
+        accept_ref[rid] = accept.astype(jnp.int32)
+        attempted_ref[rid] = attempt.astype(jnp.int32)
+        scenario_ref[rid] = scenario
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -194,7 +196,10 @@ def alloc_scan_pallas(
     num_rows = b_cpu.shape[0]
     nb, lane = rc2.shape
     assert lane == LANE, (lane, LANE)
-    chunk = min(chunk, num_rows)
+    # Wide bursts stream fewer correction-table rows per grid step, so the
+    # double-buffered pair of [chunk, B] slabs stays within 8 MiB of VMEM
+    # (the sublane tiling floors a partial slab at 8 rows).
+    chunk = min(chunk, num_rows, max(8, _SLAB_ELEMS // delta_cpu.shape[1]))
     assert num_rows % chunk == 0, (num_rows, chunk)
     grid = (num_rows // chunk,)
     # Scalar legacy totals become a K=1 federation; [K] vectors carry one
@@ -207,7 +212,11 @@ def alloc_scan_pallas(
     whole = pl.BlockSpec((nb, lane), lambda si: (0, 0))
     scalar = pl.BlockSpec((1, num_shards), lambda si: (0, 0),
                           memory_space=pltpu.SMEM)
-    row_f32 = pl.BlockSpec((chunk,), lambda si: (si,))
+    # Row scalars are read and written one at a time at a dynamic index,
+    # which Mosaic allows only in SMEM.  Each row array is one whole
+    # block: a [chunk] block of a 1-D array need not match XLA's tiling
+    # of that array, a whole one always does.
+    row = pl.BlockSpec(memory_space=pltpu.SMEM)
     # Correction-table slab: [chunk, B] for ARAS, width-1 placeholder
     # (never read) in FCFS mode.
     slab = pl.BlockSpec((chunk, delta_cpu.shape[1]), lambda si: (si, 0))
@@ -220,10 +229,10 @@ def alloc_scan_pallas(
         grid=grid,
         in_specs=[
             whole, whole, whole, whole, scalar, scalar,
-            row_f32, row_f32, row_f32, row_f32, row_f32, row_f32,
-            slab, slab, row_f32, row_f32, row_f32,
+            row, row, row, row, row, row,
+            slab, slab, row, row, row,
         ],
-        out_specs=[row_f32, row_f32, row_f32, row_f32, row_f32, row_f32],
+        out_specs=[row, row, row, row, row, row],
         out_shape=[
             jax.ShapeDtypeStruct((num_rows,), jnp.float32),
             jax.ShapeDtypeStruct((num_rows,), jnp.float32),

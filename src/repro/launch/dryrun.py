@@ -182,15 +182,9 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` normalized to a flat dict.
-
-    Depending on the jax version the method returns either a dict or a
-    one-element list of dicts (one per executable); collapse both forms.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``Compiled.cost_analysis()``, or ``{}`` where the backend gives
+    none."""
+    return compiled.cost_analysis() or {}
 
 
 def analyse(lowered, compiled) -> Dict[str, Any]:

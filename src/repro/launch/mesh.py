@@ -6,6 +6,19 @@ touches jax device state — smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes by default, which
+    ``with_sharding_constraint`` (``repro.parallel.act_sharding``) and the
+    policy-driven shardings here do not accept; every mesh of this repo
+    leaves the partitioning to the compiler.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for {shape}; have {len(devices)}. The dry-run "
@@ -39,7 +52,7 @@ def make_host_mesh(model: int = 1):
     """Whatever fits the *current* device set (tests / local runs)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def usable_cluster_devices(num_clusters: int) -> int:

@@ -17,7 +17,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -60,10 +59,10 @@ def pipeline_apply(
         mask = (stage == S - 1).astype(outbuf.dtype)
         return jax.lax.psum(outbuf * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params),
                   P(*([None] * x.ndim))),
         out_specs=P(*([None] * x.ndim)),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

@@ -33,10 +33,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.policy import ShardingPolicy
 from repro.parallel import act_sharding as act
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 pol = ShardingPolicy(mesh)
 
 

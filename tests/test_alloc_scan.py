@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.core.allocator import _burst_precompute, _core_dispatch
 from repro.core.placement import PLACEMENT_POLICIES
 from repro.engine import EngineConfig, TimingConfig, run_experiment
+from repro.kernels.alloc_scan.kernel import alloc_scan_pallas
 
 pytestmark = pytest.mark.tier1
 
@@ -46,7 +47,8 @@ def _random_burst(seed, m=37, num_rec=16, num_rows=8):
             b_attempt, b_pending, now)
 
 
-def _run_backend(case, policy, mode, backend):
+def _core_args(case, mode):
+    """The sequential core's arguments for a random burst."""
     (res_cpu, res_mem, cap_cpu, cap_mem, rec_t, rec_cpu, rec_mem, rec_done,
      b_cpu, b_mem, b_min_cpu, b_min_mem, b_wend, slots, b_attempt,
      b_pending, now) = [jnp.asarray(x) for x in case]
@@ -55,10 +57,14 @@ def _run_backend(case, policy, mode, backend):
         rec_done, b_cpu, b_mem, b_wend, slots, now, mode=mode,
     )
     rc2, rm2, cc2, cm2, tot_c, tot_m, base_c, base_m, dlt_c, dlt_m = pre
+    return (rc2, rm2, cc2, cm2, tot_c, tot_m,
+            b_cpu, b_mem, b_min_cpu, b_min_mem, base_c, base_m, dlt_c, dlt_m,
+            slots, b_attempt, b_pending)
+
+
+def _run_backend(case, policy, mode, backend):
     return _core_dispatch(
-        rc2, rm2, cc2, cm2, tot_c, tot_m,
-        b_cpu, b_mem, b_min_cpu, b_min_mem, base_c, base_m, dlt_c, dlt_m,
-        slots, b_attempt, b_pending,
+        *_core_args(case, mode),
         alpha=0.8, beta=20.0, policy=policy, mode=mode, backend=backend,
     )
 
@@ -76,6 +82,25 @@ def test_kernel_matches_scan_ref(policy, mode):
             a, b = np.asarray(a), np.asarray(b)
             assert a.dtype.kind == b.dtype.kind, name
             assert (a == b).all(), (policy, mode, seed, name, a, b)
+
+
+@pytest.mark.parametrize("mode", ["aras", "fcfs"])
+def test_kernel_row_chunks_match_scan_ref(mode):
+    """A burst walked in several grid steps (chunk < B): row scalars are
+    read and written at their burst-wide index, the correction-table
+    slab streams chunk by chunk."""
+    case = _random_burst(3, num_rec=48, num_rows=32)
+    args = _core_args(case, mode)
+    ref = _run_backend(case, "worst_fit", mode, "scan")
+    ker = alloc_scan_pallas(
+        *args[:14], *(x.astype(jnp.int32) for x in args[14:]),
+        chunk=8, alpha=0.8, beta=20.0, policy="worst_fit", mode=mode,
+        interpret=True,
+    )
+    for name, a, b in zip(
+            ("cpu", "mem", "node", "accept", "attempted", "scenario"),
+            ref, ker):
+        assert (np.asarray(a) == np.asarray(b)).all(), (mode, name, a, b)
 
 
 @pytest.mark.parametrize("allocator", ["aras", "fcfs"])

@@ -78,16 +78,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_pytree, restore_pytree
+from repro.launch.mesh import make_mesh
 
 d = sys.argv[1]
 # "save" on a 4-device (2x2) mesh
-mesh4 = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh4 = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 w = jnp.arange(64 * 64, dtype=jnp.float32).reshape(64, 64)
 sharded = jax.device_put(w, NamedSharding(mesh4, P("data", "model")))
 save_pytree({"w": sharded}, d, 1)
 
 # restore onto an 8-device (4x2) mesh — elastic scale-up
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+mesh8 = make_mesh((4, 2), ("data", "model"))
 sh = lambda path: NamedSharding(mesh8, P("data", "model"))
 out = restore_pytree({"w": jax.ShapeDtypeStruct((64, 64), jnp.float32)},
                      d, 1, sharding_fn=sh)

@@ -18,13 +18,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, json, jax
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import (analyse, collective_bytes, cost_dict,
                                  lower_decode, lower_prefill, lower_train)
 from repro.models.api import ShapeSpec, build_model
 from repro.parallel.act_sharding import activation_sharding
 from repro.parallel.policy import ShardingPolicy
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 policy = ShardingPolicy(mesh)
 
 for arch in ("llama3-8b", "olmoe-1b-7b", "falcon-mamba-7b",

@@ -83,9 +83,10 @@ _PIPELINE_PROG = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4,), ("pp",))
+mesh = make_mesh((4,), ("pp",))
 S, M, mb, d = 4, 8, 2, 16
 key = jax.random.key(0)
 stage_params = jax.random.normal(key, (S, d, d)) / jnp.sqrt(d)
@@ -122,9 +123,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.policy import ShardingPolicy
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 pol = ShardingPolicy(mesh)
 
 # FSDP+TP on a weight: [D, F] -> (('pod','data'), 'model')
