@@ -1,0 +1,8 @@
+"""The benchmark's own tests import its modules by name."""
+import sys
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent.parent
+for path in (HERE, HERE / "metrics", HERE.parent.parent / "src"):
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
