@@ -57,7 +57,7 @@ class Scenario:
     # admission control) instead of submitting everything up front.
     # stream_params are StreamEngine keyword arguments (prefetch_chunk,
     # max_pending, overload_policy); the serving telemetry lands on the
-    # RunResult (decisions/sec, p50/p99 latency, shed/deferred counts).
+    # RunResult (decisions/sec, shed/deferred counts).
     stream: bool = False
     stream_params: Mapping[str, Any] = dataclasses.field(
         default_factory=dict)
@@ -327,10 +327,8 @@ class RunResult:
     reclaimed_cpu_seconds: float = 0.0
     reclaimed_mem_seconds: float = 0.0
     # Serving telemetry (Scenario.stream=True): StreamStats wired in so
-    # grid() sweeps can gate on serving latency, not just makespan.
+    # grid() sweeps can gate on serving throughput, not just makespan.
     decisions_per_sec: float = 0.0
-    p50_latency_us: float = 0.0
-    p99_latency_us: float = 0.0
     shed_workflows: int = 0
     deferred_workflows: int = 0
     metrics: Any = dataclasses.field(repr=False, compare=False, default=None)
@@ -430,8 +428,6 @@ def run_scenario(scenario: Scenario) -> RunResult:
         reclaimed_cpu_seconds=metrics.reclaimed_cpu_seconds,
         reclaimed_mem_seconds=metrics.reclaimed_mem_seconds,
         decisions_per_sec=stats.decisions_per_sec if stats else 0.0,
-        p50_latency_us=1e6 * stats.p50_latency_s if stats else 0.0,
-        p99_latency_us=1e6 * stats.p99_latency_s if stats else 0.0,
         shed_workflows=stats.shed_workflows if stats else 0,
         deferred_workflows=stats.deferred_workflows if stats else 0,
         metrics=metrics,

@@ -66,6 +66,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.registry import ALLOCATORS
 from repro.cluster import device_state, federation
@@ -218,6 +219,11 @@ def _fill_packed(rows: np.ndarray, recs: np.ndarray,
     recs[_REC_MEM, :nrec] = window.mem
     recs[_REC_DONE] = 1.0  # padding records are done: numerically inert
     recs[_REC_DONE, :nrec] = window.done
+
+
+def _nbytes(*arrays) -> int:
+    """Bytes of the distinct device arrays a dispatch staged from the host."""
+    return sum(a.nbytes for a in {id(a): a for a in arrays}.values())
 
 
 def _packed_row_inputs(batch: TaskBatch, window: TaskWindow, now: float):
@@ -471,14 +477,17 @@ class PendingBurst:
     # deltas (the fused maintain-and-decide step): valid immediately —
     # device arrays chain asynchronously — and never synced by wait().
     state: "DeviceResidualState | None" = None
+    dispatch: int = 0  # the engine's dispatch index (trace metadata)
+    staged_bytes: int = 0  # bytes copied host→device to issue it
 
     def wait(self) -> BatchAllocation:
         """Block on the device results and map nodes back to global ids."""
         if self.outs is None:
             return BatchAllocation.empty()
         # The one host↔device sync of the whole burst.
-        cpu, mem, node, feasible, attempted, scenario = \
-            jax.device_get(self.outs)
+        with TraceAnnotation("alloc.wait", dispatch=self.dispatch):
+            cpu, mem, node, feasible, attempted, scenario = \
+                jax.device_get(self.outs)
         n = self.n
         return BatchAllocation(
             cpu=cpu[:n],
@@ -506,6 +515,7 @@ def _issue_burst(
     cap_mem=None,
     layout: FederatedLayout | None = None,
     mesh=None,
+    dispatch: int = 0,
 ) -> PendingBurst:
     """Stage → precompute → sequential core; returns without syncing.
 
@@ -513,39 +523,46 @@ def _issue_burst(
     (``repro.cluster.federation``); ``mesh`` additionally lays the tiles
     out across a ``clusters`` device mesh via ``jax.sharding``.  Node
     indices are mapped back to global node ids at ``wait()``, so callers
-    never see the padded federated index space.
+    never see the padded federated index space.  ``dispatch`` only tags
+    the ``alloc.pack`` / ``alloc.launch`` / ``alloc.wait`` trace spans.
     """
     n = batch.size
     if n == 0:
         return PendingBurst(None, 0, layout)
-    res_c, res_m, cap_c, cap_m, rows, recs, now32 = _device_inputs(
-        batch, residual_cpu, residual_mem, window, now, cap_cpu, cap_mem
-    )
-    (rc2, rm2, cc2, cm2, tot_c, tot_m, base_c, base_m, dlt_c, dlt_m) = \
-        _burst_precompute(
-            res_c, res_m, cap_c, cap_m,
-            recs["rec_t_start"], recs["rec_cpu"], recs["rec_mem"],
-            recs["rec_done"],
-            rows["b_cpu"], rows["b_mem"], rows["b_wend"], rows["b_self"],
-            now32, mode=mode, layout=layout,
+    with TraceAnnotation("alloc.pack", dispatch=dispatch) as span:
+        res_c, res_m, cap_c, cap_m, rows, recs, now32 = _device_inputs(
+            batch, residual_cpu, residual_mem, window, now, cap_cpu, cap_mem
         )
-    concrete_backend = resolve_backend(backend)
-    if mesh is not None and concrete_backend != "pallas":
-        # pallas_call has no cross-device partitioning rule (outside
-        # shard_map), so the device mesh only applies to the scan
-        # backend; the Pallas kernel instead keeps the whole federation
-        # VMEM-resident on one device.
-        rc2, rm2, cc2, cm2 = (
-            federation.shard_tiles(t, mesh) for t in (rc2, rm2, cc2, cm2))
-    outs = _core_dispatch(
-        rc2, rm2, cc2, cm2, tot_c, tot_m,
-        rows["b_cpu"], rows["b_mem"], rows["b_min_cpu"], rows["b_min_mem"],
-        base_c, base_m, dlt_c, dlt_m,
-        rows["b_self"], rows["b_attempt"], rows["b_pending"],
-        alpha=alpha, beta=beta, policy=policy, mode=mode,
-        backend=concrete_backend,
-    )
-    return PendingBurst(outs, n, layout)
+        staged = _nbytes(res_c, res_m, cap_c, cap_m, *rows.values(),
+                         *recs.values(), now32)
+        span.set_metadata(bytes=staged)
+    with TraceAnnotation("alloc.launch", dispatch=dispatch):
+        (rc2, rm2, cc2, cm2, tot_c, tot_m, base_c, base_m, dlt_c, dlt_m) = \
+            _burst_precompute(
+                res_c, res_m, cap_c, cap_m,
+                recs["rec_t_start"], recs["rec_cpu"], recs["rec_mem"],
+                recs["rec_done"],
+                rows["b_cpu"], rows["b_mem"], rows["b_wend"], rows["b_self"],
+                now32, mode=mode, layout=layout,
+            )
+        concrete_backend = resolve_backend(backend)
+        if mesh is not None and concrete_backend != "pallas":
+            # pallas_call has no cross-device partitioning rule (outside
+            # shard_map), so the device mesh only applies to the scan
+            # backend; the Pallas kernel instead keeps the whole
+            # federation VMEM-resident on one device.
+            rc2, rm2, cc2, cm2 = (
+                federation.shard_tiles(t, mesh) for t in (rc2, rm2, cc2, cm2))
+        outs = _core_dispatch(
+            rc2, rm2, cc2, cm2, tot_c, tot_m,
+            rows["b_cpu"], rows["b_mem"], rows["b_min_cpu"],
+            rows["b_min_mem"], base_c, base_m, dlt_c, dlt_m,
+            rows["b_self"], rows["b_attempt"], rows["b_pending"],
+            alpha=alpha, beta=beta, policy=policy, mode=mode,
+            backend=concrete_backend,
+        )
+    return PendingBurst(outs, n, layout, dispatch=dispatch,
+                        staged_bytes=staged)
 
 
 def _issue_state_burst(
@@ -560,6 +577,7 @@ def _issue_state_burst(
     mode: str,
     backend: str,
     updates=None,
+    dispatch: int = 0,
 ) -> PendingBurst:
     """Issue one fused dispatch against device-resident allocator state.
 
@@ -583,35 +601,38 @@ def _issue_state_burst(
             state = state.apply_updates(*updates)
         return PendingBurst(None, 0, state.layout, state=state)
     if updates is None:
-        rows, recs, now32 = _packed_row_inputs(batch, window, now)
-        outs = _state_dispatch(
+        with TraceAnnotation("alloc.pack", dispatch=dispatch) as span:
+            rows, recs, now32 = _packed_row_inputs(batch, window, now)
+            staged = _nbytes(rows, recs, now32)
+            span.set_metadata(bytes=staged)
+        with TraceAnnotation("alloc.launch", dispatch=dispatch):
+            outs = _state_dispatch(
+                state.rc2, state.rm2, state.cc2, state.cm2,
+                state.bsum_c, state.bsum_m, rows, recs, now32,
+                alpha=alpha, beta=beta, policy=policy, mode=mode,
+                backend=resolve_backend(backend), layout=state.layout,
+            )
+        return PendingBurst(outs, n, state.layout, state=state,
+                            dispatch=dispatch, staged_bytes=staged)
+    with TraceAnnotation("alloc.pack", dispatch=dispatch) as span:
+        seg, n_idx, n_blk = device_state.pack_update_segment(
+            updates[0], updates[1], updates[2],
+            state.layout, int(state.rc2.shape[0]),
+        )
+        buf, n_rows, n_rec = _pack_state_step(batch, window, now, seg)
+        span.set_metadata(bytes=buf.nbytes)
+    with TraceAnnotation("alloc.launch", dispatch=dispatch):
+        (rc2, rm2, bsum_c, bsum_m), outs = _state_step(
             state.rc2, state.rm2, state.cc2, state.cm2,
-            state.bsum_c, state.bsum_m, rows, recs, now32,
+            state.bsum_c, state.bsum_m, state.mask2, buf,
+            n_idx=n_idx, n_blk=n_blk, n_rows=n_rows, n_rec=n_rec,
             alpha=alpha, beta=beta, policy=policy, mode=mode,
             backend=resolve_backend(backend), layout=state.layout,
         )
-        return PendingBurst(outs, n, state.layout, state=state)
-    seg, n_idx, n_blk = device_state.pack_update_segment(
-        updates[0], updates[1], updates[2],
-        state.layout, int(state.rc2.shape[0]),
-    )
-    buf, n_rows, n_rec = _pack_state_step(batch, window, now, seg)
-    (rc2, rm2, bsum_c, bsum_m), outs = _state_step(
-        state.rc2, state.rm2, state.cc2, state.cm2,
-        state.bsum_c, state.bsum_m, state.mask2, buf,
-        n_idx=n_idx, n_blk=n_blk, n_rows=n_rows, n_rec=n_rec,
-        alpha=alpha, beta=beta, policy=policy, mode=mode,
-        backend=resolve_backend(backend), layout=state.layout,
-    )
     new_state = dataclasses.replace(
         state, rc2=rc2, rm2=rm2, bsum_c=bsum_c, bsum_m=bsum_m)
-    return PendingBurst(outs, n, state.layout, state=new_state)
-
-
-def _dispatch_burst(*args, **kwargs) -> BatchAllocation:
-    """Precompute → sequential core → sync back **once** (the one-shot
-    form of ``_issue_burst``)."""
-    return _issue_burst(*args, **kwargs).wait()
+    return PendingBurst(outs, n, state.layout, state=new_state,
+                        dispatch=dispatch, staged_bytes=buf.nbytes)
 
 
 class BurstReplay:
@@ -733,12 +754,20 @@ class AdaptiveAllocator:
         cap_cpu=None,
         cap_mem=None,
     ) -> BatchAllocation:
-        return _dispatch_burst(
+        """Precompute → sequential core → sync back **once**."""
+        return self.issue_batch(batch, residual_cpu, residual_mem, window,
+                                now, cap_cpu, cap_mem).wait()
+
+    def issue_batch(self, batch, residual_cpu, residual_mem, window, now,
+                    cap_cpu=None, cap_mem=None, *, dispatch: int = 0
+                    ) -> PendingBurst:
+        """``allocate_batch`` without the sync: returns the issued burst."""
+        return _issue_burst(
             batch, residual_cpu, residual_mem, window, now,
             alpha=self.alpha, beta=self.beta, policy=self.placement,
             mode=self.mode, backend=self.backend,
             cap_cpu=cap_cpu, cap_mem=cap_mem,
-            layout=self.layout, mesh=self._mesh(),
+            layout=self.layout, mesh=self._mesh(), dispatch=dispatch,
         )
 
     def create_state(self, residual_cpu, residual_mem, cap_cpu, cap_mem
@@ -758,6 +787,7 @@ class AdaptiveAllocator:
         *,
         state: DeviceResidualState,
         updates=None,
+        dispatch: int = 0,
     ) -> PendingBurst:
         """Issue one fused dispatch against device-resident state.
 
@@ -767,12 +797,14 @@ class AdaptiveAllocator:
         ``updates`` dirty set (``(nodes, res_cpu, res_mem)``, folded
         into the same dispatch; the post-update state comes back on
         ``PendingBurst.state``) must mirror the residuals
-        ``allocate_batch`` would have been handed.
+        ``allocate_batch`` would have been handed.  ``dispatch`` only
+        tags the burst's trace spans.
         """
         return _issue_state_burst(
             batch, state, window, now,
             alpha=self.alpha, beta=self.beta, policy=self.placement,
             mode=self.mode, backend=self.backend, updates=updates,
+            dispatch=dispatch,
         )
 
     def begin_replay(
@@ -839,11 +871,19 @@ class FCFSAllocator:
         cap_cpu=None,
         cap_mem=None,
     ) -> BatchAllocation:
-        return _dispatch_burst(
+        """See ``AdaptiveAllocator.allocate_batch``."""
+        return self.issue_batch(batch, residual_cpu, residual_mem, window,
+                                now, cap_cpu, cap_mem).wait()
+
+    def issue_batch(self, batch, residual_cpu, residual_mem, window, now,
+                    cap_cpu=None, cap_mem=None, *, dispatch: int = 0
+                    ) -> PendingBurst:
+        """See ``AdaptiveAllocator.issue_batch``."""
+        return _issue_burst(
             batch, residual_cpu, residual_mem, window, now,
             alpha=0.0, beta=0.0, policy=self.placement, mode=self.mode,
             backend=self.backend, cap_cpu=cap_cpu, cap_mem=cap_mem,
-            layout=self.layout, mesh=self._mesh(),
+            layout=self.layout, mesh=self._mesh(), dispatch=dispatch,
         )
 
     def create_state(self, residual_cpu, residual_mem, cap_cpu, cap_mem
@@ -862,12 +902,13 @@ class FCFSAllocator:
         *,
         state: DeviceResidualState,
         updates=None,
+        dispatch: int = 0,
     ) -> PendingBurst:
         """See ``AdaptiveAllocator.allocate_batch_async``."""
         return _issue_state_burst(
             batch, state, window, now,
             alpha=0.0, beta=0.0, policy=self.placement, mode=self.mode,
-            backend=self.backend, updates=updates,
+            backend=self.backend, updates=updates, dispatch=dispatch,
         )
 
     def begin_replay(

@@ -68,14 +68,22 @@ modes execute the same step arithmetic against the same caches, so
 decisions are bit-for-bit identical — see ``tests/test_batch_parity.py``
 — while the replay independently verifies that the fused core's in-scan
 debits and record stamps track the engine's host-side state transitions.
+
+The phases of a step are ``jax.profiler.TraceAnnotation`` spans —
+``engine.inject``, ``engine.fold``, ``engine.stage`` and
+``engine.apply``, around the allocator's ``alloc.pack``,
+``alloc.launch`` and ``alloc.wait`` — recorded only while a profiler
+session runs (README, "Tracing the scheduler").
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.config import (
     AllocatorConfig,
@@ -153,6 +161,11 @@ class EngineMetrics:
     # rows they carried in total.
     num_dispatches: int = 0
     dispatched_rows: int = 0
+    # Bytes the batched paths copied host→device to issue those
+    # dispatches (``PendingBurst.staged_bytes``; the one-time upload of
+    # the cluster when the device state is created, and the per-row
+    # replay, are not counted).
+    staged_bytes: int = 0
     # SLA accounting (paper Eqs. 2-4): per-workflow deadline violations
     sla_violations: List[Tuple[str, float, float]] = dataclasses.field(
         default_factory=list  # (workflow, finished_at, deadline)
@@ -357,30 +370,31 @@ class KubeAdaptor:
     # -------------------------------------------------------------- phases
     def _inject(self, spec: WorkflowSpec) -> None:
         """Workflow Injection Module + Interface Unit decomposition."""
-        if self._forecaster is not None:
-            # One observation per arrival: timestamp + total declared
-            # demand (the horizon-demand intensity estimate).
-            self._forecaster.observe(
-                self._now,
-                cpu=sum(t.cpu for t in spec.tasks.values()),
-                mem=sum(t.mem for t in spec.tasks.values()),
-            )
-            self.metrics.forecast_observations += 1
-        run = WorkflowRun(spec=spec, injected_at=self._now,
-                          indegree=spec.indegrees())
-        self.runs[spec.workflow_id] = run
-        # Plan-phase knowledge: projected earliest starts for every task.
-        est = spec.earliest_starts(self._now)
-        for tid, task in spec.tasks.items():
-            self.store.put(TaskRecord(
-                key=f"{spec.workflow_id}/{tid}", t_start=est[tid],
-                duration=task.duration, cpu=task.cpu, mem=task.mem,
-            ))
-        for tid in spec.roots():
-            self._push(self._now, EventKind.READY, (spec.workflow_id, tid))
-        if self._fault_cfg.workflow_timeout is not None:
-            self._push(self._now + self._fault_cfg.workflow_timeout,
-                       EventKind.WF_DEADLINE, (spec.workflow_id,))
+        with TraceAnnotation("engine.inject", tasks=spec.num_tasks):
+            if self._forecaster is not None:
+                # One observation per arrival: timestamp + total declared
+                # demand (the horizon-demand intensity estimate).
+                self._forecaster.observe(
+                    self._now,
+                    cpu=sum(t.cpu for t in spec.tasks.values()),
+                    mem=sum(t.mem for t in spec.tasks.values()),
+                )
+                self.metrics.forecast_observations += 1
+            run = WorkflowRun(spec=spec, injected_at=self._now,
+                              indegree=spec.indegrees())
+            self.runs[spec.workflow_id] = run
+            # Plan-phase knowledge: projected earliest starts for every task.
+            est = spec.earliest_starts(self._now)
+            for tid, task in spec.tasks.items():
+                self.store.put(TaskRecord(
+                    key=f"{spec.workflow_id}/{tid}", t_start=est[tid],
+                    duration=task.duration, cpu=task.cpu, mem=task.mem,
+                ))
+            for tid in spec.roots():
+                self._push(self._now, EventKind.READY, (spec.workflow_id, tid))
+            if self._fault_cfg.workflow_timeout is not None:
+                self._push(self._now + self._fault_cfg.workflow_timeout,
+                           EventKind.WF_DEADLINE, (spec.workflow_id,))
 
     # --------------------------------------------------- burst allocation
     def _batch_of(self, entries: List[Tuple[str, TaskSpec, str]]
@@ -474,8 +488,8 @@ class KubeAdaptor:
             return self._state, None
         return self._state, self.cluster.drain_dirty()
 
-    def _decide(self, entries: List[Tuple[str, TaskSpec, str]]
-                ) -> BatchAllocation:
+    def _decide(self, entries: List[Tuple[str, TaskSpec, str]],
+                dispatch: int) -> BatchAllocation:
         """One fused MAPE-K cycle for a burst of task requests.
 
         Monitor reads the incremental caches (no snapshot rebuild);
@@ -489,50 +503,50 @@ class KubeAdaptor:
         double-buffered overlap — and only then does the engine block on
         the results.
         """
+        with TraceAnnotation("engine.stage", dispatch=dispatch,
+                             rows=len(entries)):
+            if self._use_device_state:
+                state, updates = self._flush_state()
+            else:
+                res_cpu, res_mem = self.cluster.residual_view()
+                cap_cpu, cap_mem = self.cluster.capacity_view()
+            batch = self._batch_of(entries)
+            window = self._alloc_window()
         if self._use_device_state:
-            state, updates = self._flush_state()
             pending = self.allocator.allocate_batch_async(
-                self._batch_of(entries), self._alloc_window(), self._now,
-                state=state, updates=updates,
+                batch, window, self._now, state=state, updates=updates,
+                dispatch=dispatch,
             )
             self._state = pending.state
             if self.ingest_hook is not None:
                 self.ingest_hook()
-            return pending.wait()
+        else:
+            pending = self.allocator.issue_batch(
+                batch, res_cpu, res_mem, window, self._now,
+                cap_cpu=cap_cpu, cap_mem=cap_mem, dispatch=dispatch,
+            )
+        self.metrics.staged_bytes += pending.staged_bytes
+        return pending.wait()
+
+    def _replay_rows(self, entries: List[Tuple[str, TaskSpec, str]]):
+        """Yield (feasible, attempted, Allocation) per entry, in order.
+
+        Per-task mode replays the burst one dispatch per row, reading the
+        engine's live residual caches *after* each preceding bind (the
+        generator suspends at ``yield`` while the consumer applies the
+        decision) — the sequential MAPE-K reference.
+        """
         res_cpu, res_mem = self.cluster.residual_view()
         cap_cpu, cap_mem = self.cluster.capacity_view()
-        return self.allocator.allocate_batch(
+        replay = self.allocator.begin_replay(
             self._batch_of(entries), res_cpu, res_mem,
             self._alloc_window(), self._now,
             cap_cpu=cap_cpu, cap_mem=cap_mem,
         )
-
-    def _decision_rows(self, entries: List[Tuple[str, TaskSpec, str]]):
-        """Yield (feasible, attempted, Allocation) per entry, in order.
-
-        Batched mode decides everything in one fused dispatch up front;
-        per-task mode replays the same burst one dispatch per row, reading
-        the engine's live residual caches *after* each preceding bind (the
-        generator suspends at ``yield`` while the consumer applies the
-        decision) — the sequential MAPE-K reference.
-        """
-        if self.cfg.alloc.batch_allocation:
-            result = self._decide(entries)
-            for i in range(len(entries)):
-                yield (bool(result.feasible[i]), bool(result.attempted[i]),
-                       allocation_at(result, i))
-        else:
+        for i in range(len(entries)):
             res_cpu, res_mem = self.cluster.residual_view()
-            cap_cpu, cap_mem = self.cluster.capacity_view()
-            replay = self.allocator.begin_replay(
-                self._batch_of(entries), res_cpu, res_mem,
-                self._alloc_window(), self._now,
-                cap_cpu=cap_cpu, cap_mem=cap_mem,
-            )
-            for i in range(len(entries)):
-                res_cpu, res_mem = self.cluster.residual_view()
-                alloc, attempted = replay.step(i, res_cpu, res_mem)
-                yield alloc.feasible, attempted, alloc
+            alloc, attempted = replay.step(i, res_cpu, res_mem)
+            yield alloc.feasible, attempted, alloc
 
     def _bind(self, wf_id: str, task: TaskSpec, alloc: Allocation) -> None:
         """Execute phase: Containerized Executor creates the pod."""
@@ -613,37 +627,49 @@ class KubeAdaptor:
                        for wf_id, task in self._pending] + entries
         if not entries:
             return
+        dispatch = self.metrics.num_dispatches
         self.metrics.dispatched_rows += len(entries)
-        self.metrics.num_dispatches += (
-            1 if self.cfg.alloc.batch_allocation else len(entries))
         kept: Deque[Tuple[str, TaskSpec]] = deque()
         failed: List[Tuple[str, TaskSpec]] = []
         dying: Dict[str, None] = {}  # insertion-ordered workflow set
         bound_any = False
         waited_any = False
-        rows = self._decision_rows(entries)
-        for (wf_id, task, origin), (feasible, attempted, alloc) in zip(
-                entries, rows):
-            if feasible:
-                self._bind(wf_id, task, alloc)
-                bound_any = True
-            elif origin == "pending":
-                # Skipped rows (head-of-line) were never attempted and do
-                # not count as waits, matching the sequential retry loop.
-                if attempted:
+        if self.cfg.alloc.batch_allocation:
+            # One fused dispatch decides every row before any applies.
+            self.metrics.num_dispatches += 1
+            result = self._decide(entries, dispatch)
+            rows = ((bool(result.feasible[i]), bool(result.attempted[i]),
+                     allocation_at(result, i)) for i in range(len(entries)))
+            applying = TraceAnnotation("engine.apply", dispatch=dispatch,
+                                       rows=len(entries))
+        else:
+            self.metrics.num_dispatches += len(entries)
+            rows = self._replay_rows(entries)
+            applying = contextlib.nullcontext()
+        with applying:
+            for (wf_id, task, origin), (feasible, attempted, alloc) in zip(
+                    entries, rows):
+                if feasible:
+                    self._bind(wf_id, task, alloc)
+                    bound_any = True
+                elif origin == "pending":
+                    # Skipped rows (head-of-line) were never attempted and
+                    # do not count as waits, matching the sequential retry
+                    # loop.
+                    if attempted:
+                        self.metrics.num_waits += 1
+                        waited_any = True
+                        if self._budget_exhausted(wf_id, task):
+                            dying[wf_id] = None
+                            continue
+                    kept.append((wf_id, task))
+                else:
                     self.metrics.num_waits += 1
                     waited_any = True
                     if self._budget_exhausted(wf_id, task):
                         dying[wf_id] = None
                         continue
-                kept.append((wf_id, task))
-            else:
-                self.metrics.num_waits += 1
-                waited_any = True
-                if self._budget_exhausted(wf_id, task):
-                    dying[wf_id] = None
-                    continue
-                failed.append((wf_id, task))
+                    failed.append((wf_id, task))
         if include_pending:
             kept.extend(failed)
             self._pending = kept
@@ -694,7 +720,7 @@ class KubeAdaptor:
         from the predicted next inter-arrival gap.  Both engine modes
         share this drain; they differ only in how the group is decided
         (one fused dispatch vs the row-at-a-time replay — see
-        ``_decision_rows``).
+        ``_allocate_group``).
         """
         window = self.fold_window()
         if self._forecaster is not None and self._forecaster.ready:
@@ -704,41 +730,43 @@ class KubeAdaptor:
         include_pending = False
         entries: List[Tuple[str, TaskSpec, str]] = []
         event: Optional[Event] = first
-        while event is not None:
-            self._now = event.t
-            if event.kind is EventKind.INJECT:
-                self._inject(*event.payload)
-            elif event.kind is EventKind.COMPLETE:
-                # Folded only while the burst is idle (see below).
-                self._complete(*event.payload)
-            elif event.kind is EventKind.DELETE:
-                self.cluster.delete(*event.payload)
-            elif event.kind is EventKind.RETRY:
-                # Backoff gate: retries scheduled before the gate reopens
-                # leave the pending queue parked (the gate-time RETRY
-                # pushed by the failed round reopens it).
-                include_pending = self._now >= self._retry_gate
-            elif event.kind is EventKind.READY:
-                wf_id, tid = event.payload
-                if wf_id in self._failed_workflows:
-                    pass  # workflow already terminated FAILED
-                else:
-                    task = self.runs[wf_id].spec.tasks[tid]
-                    if task.cpu == 0 and task.mem == 0:
-                        # Virtual entrance/exit: complete instantly, no pod.
-                        self._task_done(wf_id, tid)
+        with TraceAnnotation("engine.fold"):
+            while event is not None:
+                self._now = event.t
+                if event.kind is EventKind.INJECT:
+                    self._inject(*event.payload)
+                elif event.kind is EventKind.COMPLETE:
+                    # Folded only while the burst is idle (see below).
+                    self._complete(*event.payload)
+                elif event.kind is EventKind.DELETE:
+                    self.cluster.delete(*event.payload)
+                elif event.kind is EventKind.RETRY:
+                    # Backoff gate: retries scheduled before the gate reopens
+                    # leave the pending queue parked (the gate-time RETRY
+                    # pushed by the failed round reopens it).
+                    include_pending = self._now >= self._retry_gate
+                elif event.kind is EventKind.READY:
+                    wf_id, tid = event.payload
+                    if wf_id in self._failed_workflows:
+                        pass  # workflow already terminated FAILED
                     else:
-                        entries.append((wf_id, task, "ready"))
-            else:  # HEAL
-                wf_id, task = event.payload
-                if wf_id not in self._failed_workflows:
-                    self.metrics.realloc_events.append(
-                        (self._now, f"{wf_id}/{task.task_id}")
-                    )
-                    entries.append((wf_id, task, "heal"))
-            idle = not entries and not (include_pending and self._pending)
-            event = self.queue.pop_mergeable(first.t, deadline,
-                                             fold_capacity_free=idle)
+                        task = self.runs[wf_id].spec.tasks[tid]
+                        if task.cpu == 0 and task.mem == 0:
+                            # Virtual entrance/exit: completes at once,
+                            # no pod.
+                            self._task_done(wf_id, tid)
+                        else:
+                            entries.append((wf_id, task, "ready"))
+                else:  # HEAL
+                    wf_id, task = event.payload
+                    if wf_id not in self._failed_workflows:
+                        self.metrics.realloc_events.append(
+                            (self._now, f"{wf_id}/{task.task_id}")
+                        )
+                        entries.append((wf_id, task, "heal"))
+                idle = not entries and not (include_pending and self._pending)
+                event = self.queue.pop_mergeable(first.t, deadline,
+                                                 fold_capacity_free=idle)
         self._allocate_group(entries, include_pending)
 
     # --------------------------------------------------------- completion

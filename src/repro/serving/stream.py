@@ -24,11 +24,11 @@ the pieces this engine already has:
   hidden under device compute.  Folding rules are unaffected: those
   arrivals are all beyond the current fold deadline, so they cannot
   join the in-flight decision; they are simply queued earlier.
-* **Serving telemetry.**  Each step is wall-clock timed; steps that
-  dispatched allocation rows contribute per-decision latency samples
-  (step wall time amortized over the rows it decided).  ``serve()``
-  returns :class:`StreamStats` with sustained decisions/sec and
-  p50/p99 per-decision latency next to the usual engine metrics.
+* **Serving telemetry.**  ``serve()`` returns :class:`StreamStats`
+  with sustained decisions/sec over the run's wall time next to the
+  usual engine metrics.  Where the time of a step goes is read from a
+  profiler trace of the engine's spans (README, "Tracing the
+  scheduler").
 * **Admission control (backpressure).**  With ``max_pending`` set, the
   pump watches the engine's pending admission queue; while its backlog
   exceeds the bound, new arrivals are *shed* (dropped and counted — the
@@ -50,22 +50,18 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from repro.engine.kubeadaptor import EngineMetrics, KubeAdaptor
 from repro.workflows.spec import WorkflowSpec
 
 
 @dataclasses.dataclass
 class StreamStats:
-    """Serving-loop report: throughput + tail latency + engine metrics."""
+    """Serving-loop report: throughput + engine metrics."""
 
     decisions: int  # allocation rows decided (= metrics.dispatched_rows)
     dispatches: int  # fused dispatches issued
     wall_seconds: float  # total serve() wall time
     decisions_per_sec: float  # sustained throughput over the whole run
-    p50_latency_s: float  # per-decision latency percentiles, wall time
-    p99_latency_s: float  # of the deciding step / rows it decided
     overlapped_ingests: int  # arrivals submitted under in-flight dispatches
     shed_workflows: int  # arrivals dropped by admission control
     deferred_workflows: int  # arrivals withheld (at least once) by backlog
@@ -78,8 +74,6 @@ class StreamStats:
             "dispatches": self.dispatches,
             "wall_seconds": self.wall_seconds,
             "decisions_per_sec": self.decisions_per_sec,
-            "p50_latency_s": self.p50_latency_s,
-            "p99_latency_s": self.p99_latency_s,
             "overlapped_ingests": self.overlapped_ingests,
             "shed_workflows": self.shed_workflows,
             "deferred_workflows": self.deferred_workflows,
@@ -195,32 +189,22 @@ class StreamEngine:
     def serve(self) -> StreamStats:
         """Run the stream to completion; returns the serving report."""
         engine = self.engine
-        latencies: List[float] = []
         t_serve0 = time.perf_counter()
         while True:
             self._pump()
             if not engine.queue:
                 break  # arrivals exhausted and the event loop drained
-            rows_before = engine.metrics.dispatched_rows
-            t0 = time.perf_counter()
             engine.step()
-            dt = time.perf_counter() - t0
             if engine.cfg.invariant_checks:
                 engine.cluster.check_invariants()
-            rows = engine.metrics.dispatched_rows - rows_before
-            if rows > 0:
-                latencies.extend([dt / rows] * rows)
         wall = time.perf_counter() - t_serve0
         metrics = engine.finalize()
-        lat = np.asarray(latencies, np.float64)
         return StreamStats(
             decisions=metrics.dispatched_rows,
             dispatches=metrics.num_dispatches,
             wall_seconds=wall,
             decisions_per_sec=(metrics.dispatched_rows / wall
                                if wall > 0 else 0.0),
-            p50_latency_s=float(np.percentile(lat, 50)) if lat.size else 0.0,
-            p99_latency_s=float(np.percentile(lat, 99)) if lat.size else 0.0,
             overlapped_ingests=self.overlapped_ingests,
             shed_workflows=self.shed_workflows,
             deferred_workflows=self.deferred_workflows,

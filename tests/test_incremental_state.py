@@ -380,11 +380,9 @@ def test_stream_stats_schema():
     stats = serve_stream(_engine("fcfs", 1, 0.0, True), _ARRIVALS)
     d = stats.to_dict()
     assert set(d) == {"decisions", "dispatches", "wall_seconds",
-                      "decisions_per_sec", "p50_latency_s",
-                      "p99_latency_s", "overlapped_ingests",
+                      "decisions_per_sec", "overlapped_ingests",
                       "shed_workflows", "deferred_workflows"}
     assert d["shed_workflows"] == 0 and d["deferred_workflows"] == 0
     assert d["decisions"] > 0 and d["dispatches"] > 0
     assert d["decisions_per_sec"] > 0.0
-    assert 0.0 < d["p50_latency_s"] <= d["p99_latency_s"]
     assert all(isinstance(v, (int, float)) for v in d.values())
