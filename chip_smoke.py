@@ -199,7 +199,7 @@ def four_chip_phase(size: dict, seed: int, on_tpu: bool) -> bool:
         return False
     pods = size["burst"] + size["arrivals"]
     ok = True
-    core = allocator._core_dispatch
+    core = allocator._packed_core_dispatch
     for algorithm in ("aras", "fcfs"):
         # Off the TPU "auto" is "scan": rehearse the kernel by name.
         for backend in ("auto" if on_tpu else "pallas", "scan"):
@@ -213,15 +213,15 @@ def four_chip_phase(size: dict, seed: int, on_tpu: bool) -> bool:
                 outs = core(rc2, *args, **kwargs)
                 if not placed:
                     placed["tiles"] = devices_of(rc2)
-                    placed["outputs"] = devices_of(outs[2])
+                    placed["outputs"] = devices_of(outs)
                 return outs
 
-            allocator._core_dispatch = spy
+            allocator._packed_core_dispatch = spy
             try:
                 sharded = serve(cfg.evolve(cluster_sharding="auto"), size,
                                 seed)
             finally:
-                allocator._core_dispatch = core
+                allocator._packed_core_dispatch = core
             mesh = sharded["engine"].allocator._mesh()
             mesh_ids = (None if mesh is None
                         else sorted(d.id for d in mesh.devices.flat))

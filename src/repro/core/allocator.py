@@ -66,6 +66,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import TraceAnnotation
 
 from repro.api.registry import ALLOCATORS
@@ -234,6 +235,44 @@ def _packed_row_inputs(batch: TaskBatch, window: TaskWindow, now: float):
     return jnp.asarray(rows), jnp.asarray(recs), jnp.float32(now)
 
 
+def _pack_outs(cpu, mem, node, feasible, attempted, scenario):
+    """The six decision rows as one ``int32[6, B]`` buffer (traceable).
+
+    The device→host fetch costs per transfer, not per byte, so every
+    jitted entry that decides returns this one buffer and
+    ``PendingBurst.wait`` fetches it once.  The quotas ride as their
+    float32 bit patterns (``bitcast_convert_type``: exact, -0.0 and NaN
+    kept), the flags as 0/1.  The quotas are stacked before the bitcast
+    so that XLA fuses the whole pack into one copy: no op of its own
+    reads the kernel's outputs, which keeps the kernel the one device
+    op that trace readers find by its name
+    (``tests/test_chip_compile.py``).
+    """
+    quotas = lax.bitcast_convert_type(jnp.stack([cpu, mem]), jnp.int32)
+    return jnp.concatenate([quotas, jnp.stack([
+        node.astype(jnp.int32),
+        feasible.astype(jnp.int32),
+        attempted.astype(jnp.int32),
+        scenario.astype(jnp.int32),
+    ])])
+
+
+def _unpack_outs(packed: np.ndarray, n: int):
+    """Host inverse of :func:`_pack_outs` over the first ``n`` rows."""
+    cpu, mem, node, feasible, attempted, scenario = packed[:, :n]
+    return (cpu.view(np.float32), mem.view(np.float32), node,
+            feasible != 0, attempted != 0, scenario)
+
+
+def alloc_scan_packed(*args, **kwargs):
+    """``alloc_scan`` with its six outputs packed by :func:`_pack_outs`.
+
+    Jitted, its name makes the compiled module ``jit_alloc_scan_packed``,
+    which trace readers match as the sequential core by its prefix.
+    """
+    return _pack_outs(*alloc_scan(*args, **kwargs))
+
+
 def _decide_packed(rc2, rm2, cc2, cm2, bsum_c, bsum_m, rows, recs, now,
                    *, alpha, beta, policy, mode, backend, layout):
     """Traceable device-resident decision over packed staging arrays.
@@ -243,7 +282,8 @@ def _decide_packed(rc2, rm2, cc2, cm2, bsum_c, bsum_m, rows, recs, now,
     (``repro.cluster.device_state``), the carried totals come from the
     incrementally-maintained block sums via the same fixed-order reduce
     the re-pad path uses, and the hoisted demand tables feed straight
-    into ``alloc_scan`` without re-crossing a dispatch boundary.
+    into ``alloc_scan`` without re-crossing a dispatch boundary.  The
+    decisions come back packed (:func:`_pack_outs`).
     """
     b_cpu, b_mem = rows[_ROW_CPU], rows[_ROW_MEM]
     b_min_cpu, b_min_mem = rows[_ROW_MIN_CPU], rows[_ROW_MIN_MEM]
@@ -258,7 +298,7 @@ def _decide_packed(rc2, rm2, cc2, cm2, bsum_c, bsum_m, rows, recs, now,
         recs[_REC_T_START], recs[_REC_CPU], recs[_REC_MEM], rec_done,
         b_cpu, b_mem, b_wend, b_self, now, mode=mode,
     )
-    return alloc_scan(
+    return alloc_scan_packed(
         rc2, rm2, cc2, cm2, tot_cpu, tot_mem,
         b_cpu, b_mem, b_min_cpu, b_min_mem, base_cpu, base_mem,
         delta_cpu, delta_mem, b_self, b_attempt, b_pending,
@@ -336,13 +376,13 @@ def _state_step(
     re-derive the dirty block sums, then run the fused decision against
     the updated state — one host→device copy, one dispatch, per burst.
     Returns the updated ``(rc2, rm2, bsum_c, bsum_m)`` carry (device
-    arrays the next step chains on without syncing) plus the decision
-    outputs.  The residual tiles and block sums are **donated**: the
-    input state is consumed (its buffers updated in place) and only the
-    returned state is valid afterwards.  Ops are identical to
-    ``apply_updates`` followed by ``_state_dispatch``, so decisions stay
-    bit-for-bit with the re-pad path
-    (``tests/test_incremental_state.py``).
+    arrays the next step chains on without syncing) plus the packed
+    decision outputs (:func:`_pack_outs`).  The residual tiles and block
+    sums are **donated**: the input state is consumed (its buffers
+    updated in place) and only the returned state is valid afterwards.
+    Ops are identical to ``apply_updates`` followed by
+    ``_state_dispatch``, so decisions stay bit-for-bit with the re-pad
+    path (``tests/test_incremental_state.py``).
     """
     u = 3 * n_idx + n_blk
     rc2, rm2, bsum_c, bsum_m = device_state.apply_packed(
@@ -357,8 +397,16 @@ def _state_step(
     return (rc2, rm2, bsum_c, bsum_m), outs
 
 
+# The sequential core as one dispatch, its six outputs apart: the entry
+# the kernel parity tests compare backends through.
 _core_dispatch = jax.jit(
     alloc_scan,
+    static_argnames=("alpha", "beta", "policy", "mode", "backend"),
+)
+# The re-pad and mesh path's sequential core, outputs packed
+# (``_issue_burst``).
+_packed_core_dispatch = jax.jit(
+    alloc_scan_packed,
     static_argnames=("alpha", "beta", "policy", "mode", "backend"),
 )
 
@@ -461,16 +509,18 @@ def _device_inputs(
 class PendingBurst:
     """A fused dispatch issued but not yet synced back to the host.
 
-    JAX dispatch is asynchronous: once ``_core_dispatch`` returns, the
+    JAX dispatch is asynchronous: once the jitted call returns, the
     device is computing while the host is free — so the engine can fold
     queued events (and flush dirty-tile updates into the *next* state)
     before paying the one blocking ``wait()`` sync of the burst.  The
     split is what makes the double-buffered overlap of the streaming
     engine possible; ``wait()`` is exactly the sync the one-shot path
-    always did, so decisions are unaffected.
+    always did, so decisions are unaffected.  The sync is one
+    device→host transfer of one packed ``int32[6, pow2(B)]`` buffer
+    (:func:`_pack_outs`), unpacked on the host.
     """
 
-    outs: tuple | None  # device arrays; None = empty burst
+    outs: jax.Array | None  # packed decisions; None = empty burst
     n: int
     layout: FederatedLayout | None
     # Post-update device state when the dispatch also folded dirty-node
@@ -480,22 +530,29 @@ class PendingBurst:
     dispatch: int = 0  # the engine's dispatch index (trace metadata)
     staged_bytes: int = 0  # bytes copied host→device to issue it
 
+    @property
+    def fetched_bytes(self) -> int:
+        """Bytes ``wait()`` copies device→host (``24 * pow2(B)``)."""
+        return 0 if self.outs is None else self.outs.nbytes
+
     def wait(self) -> BatchAllocation:
         """Block on the device results and map nodes back to global ids."""
         if self.outs is None:
             return BatchAllocation.empty()
-        # The one host↔device sync of the whole burst.
-        with TraceAnnotation("alloc.wait", dispatch=self.dispatch):
-            cpu, mem, node, feasible, attempted, scenario = \
-                jax.device_get(self.outs)
-        n = self.n
+        # The one host↔device sync of the whole burst: one transfer of
+        # the one packed buffer.
+        with TraceAnnotation("alloc.wait", dispatch=self.dispatch,
+                             bytes=self.fetched_bytes):
+            packed = jax.device_get(self.outs)
+        cpu, mem, node, feasible, attempted, scenario = _unpack_outs(
+            packed, self.n)
         return BatchAllocation(
-            cpu=cpu[:n],
-            mem=mem[:n],
-            node=federation.global_nodes(node[:n], self.layout),
-            feasible=feasible[:n],
-            attempted=attempted[:n],
-            scenario=scenario[:n],
+            cpu=cpu,
+            mem=mem,
+            node=federation.global_nodes(node, self.layout),
+            feasible=feasible,
+            attempted=attempted,
+            scenario=scenario,
         )
 
 
@@ -553,7 +610,7 @@ def _issue_burst(
             # federation VMEM-resident on one device.
             rc2, rm2, cc2, cm2 = (
                 federation.shard_tiles(t, mesh) for t in (rc2, rm2, cc2, cm2))
-        outs = _core_dispatch(
+        outs = _packed_core_dispatch(
             rc2, rm2, cc2, cm2, tot_c, tot_m,
             rows["b_cpu"], rows["b_mem"], rows["b_min_cpu"],
             rows["b_min_mem"], base_c, base_m, dlt_c, dlt_m,

@@ -166,6 +166,9 @@ class EngineMetrics:
     # the cluster when the device state is created, and the per-row
     # replay, are not counted).
     staged_bytes: int = 0
+    # Bytes those dispatches copied device→host: one packed decision
+    # buffer each (``PendingBurst.fetched_bytes``).
+    fetched_bytes: int = 0
     # SLA accounting (paper Eqs. 2-4): per-workflow deadline violations
     sla_violations: List[Tuple[str, float, float]] = dataclasses.field(
         default_factory=list  # (workflow, finished_at, deadline)
@@ -526,6 +529,7 @@ class KubeAdaptor:
                 cap_cpu=cap_cpu, cap_mem=cap_mem, dispatch=dispatch,
             )
         self.metrics.staged_bytes += pending.staged_bytes
+        self.metrics.fetched_bytes += pending.fetched_bytes
         return pending.wait()
 
     def _replay_rows(self, entries: List[Tuple[str, TaskSpec, str]]):

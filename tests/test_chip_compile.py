@@ -13,6 +13,7 @@ compiles, since a program compiled for an absent chip cannot be read
 back from it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro.cluster.federation import LANE, FederatedLayout
-from repro.core.allocator import _core_dispatch, _state_step
+from repro.core.allocator import _packed_core_dispatch, _state_step
 from repro.core.types import DEFAULT_ALPHA, DEFAULT_BETA
 from repro.kernels.alloc_scan import ops
 from repro.kernels.alloc_scan.kernel import alloc_scan_pallas
@@ -30,6 +31,12 @@ from repro.kernels.alloc_scan.kernel import alloc_scan_pallas
 pytestmark = pytest.mark.tier1
 
 NODES = 5_000  # Kubernetes' documented single-cluster limit
+# How a device trace names the kernel: a trace reader finds the kernel's
+# events by these names in each op's instruction text.
+KERNEL_NAME = re.compile(r"_scan_kernel|alloc_scan_pallas")
+# Instructions that launch no device op of their own.
+NO_DEVICE_OP = ("get-tuple-element", "bitcast", "tuple", "parameter",
+                "constant")
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +94,22 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_named_ops(compiled) -> int:
+    """Device ops of the entry computation whose instruction text (name
+    and operands, without metadata) names the kernel."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    count = 0
+    for line in entry.splitlines()[1:]:
+        inst = line.split(", metadata=")[0]
+        opcode = re.search(r"= .*? ([\w-]+)\(", inst)
+        if opcode and opcode.group(1) not in NO_DEVICE_OP \
+                and KERNEL_NAME.search(inst):
+            count += 1
+    return count
+
+
 @pytest.mark.parametrize("mode,policy,rows", [
     ("aras", "worst_fit", 256),
     ("aras", "worst_fit", 1024),
@@ -126,16 +149,19 @@ def test_federation_scan_core_compiles_across_four_chips(topo):
     for i in range(4):
         args[i] = jax.ShapeDtypeStruct(args[i].shape, args[i].dtype,
                                        sharding=clusters)
-    compiled = _core_dispatch.lower(
+    compiled = _packed_core_dispatch.lower(
         *args, alpha=DEFAULT_ALPHA, beta=DEFAULT_BETA, policy="worst_fit",
         mode="aras", backend="scan",
     ).compile()
     assert compiled.memory_analysis() is not None
 
 
-def test_fused_state_step_holds_the_kernel(one_chip, monkeypatch):
+@pytest.mark.parametrize("n_rows", [8, 1024])
+def test_fused_state_step_holds_the_kernel(one_chip, monkeypatch, n_rows):
     """The served path's one jitted dispatch, maintain-and-decide, with
-    the compiled kernel inside (``backend="pallas"`` on a TPU)."""
+    the compiled kernel inside (``backend="pallas"`` on a TPU), and the
+    kernel the one device op named after it: packing the decisions adds
+    no op that a trace would count as the kernel."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
 
     def spec(shape, dtype=jnp.float32):
@@ -144,7 +170,7 @@ def test_fused_state_step_holds_the_kernel(one_chip, monkeypatch):
     nb = FederatedLayout.single(NODES).num_blocks
     tiles, bsum = spec((nb, LANE)), spec((nb,))
     n_idx = n_blk = 8
-    n_rows = n_rec = 1024
+    n_rec = 1024
     buf = spec((3 * n_idx + n_blk + 8 * n_rows + 4 * n_rec + 1,))
     try:
         compiled = _state_step.lower(
@@ -158,3 +184,4 @@ def test_fused_state_step_holds_the_kernel(one_chip, monkeypatch):
         # _state_step at these shapes may reuse it.
         jax.clear_caches()
     assert _has_kernel(compiled)
+    assert _kernel_named_ops(compiled) == 1

@@ -6,8 +6,9 @@ stream is served under ``jax.profiler.trace`` and the ``.xplane.pb`` is
 read back with ``ProfileData``: every span is there, the per-dispatch
 spans carry the engine's dispatch index once each and in order,
 injection nests only where it may, ``EngineMetrics.staged_bytes`` equals
-a count made from the allocator's inputs alone, and tracing changes no
-decision.
+a count made from the allocator's inputs alone, ``EngineMetrics.
+fetched_bytes`` equals the bytes of the ``alloc.wait`` spans (one packed
+``int32[6, pow2(B)]`` per dispatch), and tracing changes no decision.
 """
 import glob
 import os
@@ -71,8 +72,11 @@ def _pow2(n: int, floor: int = 1) -> int:
     return 1 << (max(n, floor) - 1).bit_length()
 
 
-def _count_staging(eng: KubeAdaptor, expected: list) -> None:
-    """Append, per dispatch, the bytes its inputs imply to ``expected``.
+def _count_staging(eng: KubeAdaptor, expected: list, fetched: list
+                   ) -> None:
+    """Append, per dispatch, the bytes its inputs imply to ``expected``,
+    and the bytes of its packed decisions (``24 * pow2(B)``) to
+    ``fetched``.
 
     Device-resident path: one flat float32 buffer of the dirty segment
     (``3 * n_idx + n_blk``, buckets floored at 8), ``[8, pow2(B)]`` rows,
@@ -93,6 +97,7 @@ def _count_staging(eng: KubeAdaptor, expected: list) -> None:
             seg = (3 * _pow2(nodes.size, 8)
                    + _pow2(np.unique(nodes // 128).size, 8))
         expected.append(4 * (seg + rows + recs + 1))
+        fetched.append(24 * _pow2(batch.size))
         return issue_async(batch, window, now, state=state, updates=updates,
                            dispatch=dispatch)
 
@@ -101,6 +106,7 @@ def _count_staging(eng: KubeAdaptor, expected: list) -> None:
         expected.append(4 * 4 * res_cpu.size
                         + _pow2(batch.size) * (6 * 4 + 2)
                         + _pow2(window.t_start.size) * (3 * 4 + 1) + 4)
+        fetched.append(24 * _pow2(batch.size))
         return issue(batch, res_cpu, res_mem, window, now, cap_cpu, cap_mem,
                      dispatch=dispatch)
 
@@ -111,16 +117,18 @@ class Served(NamedTuple):
     incremental: bool
     metrics: object  # EngineMetrics
     expected: list  # bytes per dispatch, from the allocator's inputs
+    fetched: list  # bytes per dispatch, from the burst's width
     spans: list  # (name, start_ns, end_ns, metadata)
 
 
 def _serve(incremental: bool, trace_dir=None) -> Served:
     eng = _engine(incremental)
     expected: list = []
-    _count_staging(eng, expected)
+    fetched: list = []
+    _count_staging(eng, expected, fetched)
     if trace_dir is None:
         metrics = serve_stream(eng, _arrivals()).metrics
-        return Served(incremental, metrics, expected, [])
+        return Served(incremental, metrics, expected, fetched, [])
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     with jax.profiler.trace(str(trace_dir), profiler_options=opts):
@@ -137,7 +145,7 @@ def _serve(incremental: bool, trace_dir=None) -> Served:
                     start = int(ev.start_ns)
                     spans.append((ev.name, start, start + int(ev.duration_ns),
                                   dict(ev.stats)))
-    return Served(incremental, metrics, expected, spans)
+    return Served(incremental, metrics, expected, fetched, spans)
 
 
 @pytest.fixture(scope="module", params=[True, False],
@@ -202,9 +210,21 @@ def test_staged_bytes_match_an_independent_count(traced):
         == metrics.staged_bytes
 
 
+def test_fetched_bytes_match_the_wait_spans(traced):
+    """One packed buffer per dispatch comes back: ``alloc.wait`` carries
+    its bytes, and they sum to ``EngineMetrics.fetched_bytes``."""
+    metrics, fetched, spans = traced.metrics, traced.fetched, traced.spans
+    assert len(fetched) == metrics.num_dispatches
+    waits = _named(spans, "alloc.wait")
+    assert all("bytes" in s[3] for s in waits)
+    assert sorted(s[3]["bytes"] for s in waits) == sorted(fetched)
+    assert sum(s[3]["bytes"] for s in waits) == metrics.fetched_bytes
+
+
 def test_tracing_changes_no_decision(traced):
     on, off = traced.metrics, _serve(traced.incremental).metrics
     assert on.alloc_trace == off.alloc_trace
     assert on.num_dispatches == off.num_dispatches
     assert on.staged_bytes == off.staged_bytes
+    assert on.fetched_bytes == off.fetched_bytes
     assert on.makespan == off.makespan
