@@ -22,7 +22,10 @@ Each bind is recorded from a wrapper around the engine's cluster
 the check needs.  With tracing on, every ``step()`` runs inside a
 ``TraceAnnotation`` named ``step:<event kind>``, waits for the clock in
 ``pace_wait`` and engine rebuilds in ``episode_reset``, so the trace
-reduction can put host time beside device time.
+reduction can put host time beside device time.  The window's change in
+every integer counter of the engine's ``EngineMetrics`` is summed over
+its episodes (``Window.counters``), read between steps and never inside
+one.
 """
 from __future__ import annotations
 
@@ -31,8 +34,7 @@ import copy
 import dataclasses
 import heapq
 import time
-from typing import Dict, List, Tuple
-
+from typing import Dict, List, Optional, Tuple
 
 
 def to_program(stream):
@@ -82,6 +84,16 @@ class Window:
     # The five longest paced steps: (seconds, event kind, simulated
     # time, rows dispatched).
     slowest: List[tuple] = dataclasses.field(default_factory=list)
+    # Change in each integer field of ``EngineMetrics`` over the window,
+    # summed over its episodes.
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
+    trace: Optional[object] = None  # tracing.Reduction, when traced
+
+    def add_counters(self, eng, before: Dict[str, int]) -> None:
+        """Add ``eng``'s counters less ``before`` to the window's."""
+        for name, value in counters(eng.metrics).items():
+            self.counters[name] = (self.counters.get(name, 0) + value
+                                   - before.get(name, 0))
 
 
 class Driver:
@@ -171,6 +183,13 @@ def _count(eng) -> Tuple[int, int]:
     return eng.metrics.num_dispatches, eng.metrics.dispatched_rows
 
 
+def counters(metrics) -> Dict[str, int]:
+    """Every integer field of an ``EngineMetrics``."""
+    values = {f.name: getattr(metrics, f.name)
+              for f in dataclasses.fields(metrics)}
+    return {name: v for name, v in values.items() if type(v) is int}
+
+
 def paced(driver: Driver, eng, episode: Episode, stream, start: int,
           t0: float, scale: float, seconds: float, window: Window,
           cap_s: float = 60.0) -> None:
@@ -187,6 +206,7 @@ def paced(driver: Driver, eng, episode: Episode, stream, start: int,
     w_end = w0 + seconds
     i = start
     n_binds = len(episode.order)
+    before = counters(eng.metrics)
     while True:
         head = eng.queue.peek()
         t_arr = stream[i][0] if i < len(stream) else float("inf")
@@ -231,6 +251,7 @@ def paced(driver: Driver, eng, episode: Episode, stream, start: int,
             window.lags.append(returned - (w0 + (t_bind - t0) / scale))
         n_binds = len(episode.order)
     window.overrun_s = max(window.overrun_s, time.perf_counter() - w_end)
+    window.add_counters(eng, before)
     window.offered += sum(1 for t, _ in stream[start:] if t <= horizon)
     head = eng.queue.peek()
     if head is not None and head.t <= horizon:
@@ -260,6 +281,7 @@ def closed(driver: Driver, horizon: float, seconds: float, window: Window,
         window.episodes.append(episode)
         with driver.span("episode_reset"):
             eng = driver.engine(episode)
+        before = counters(eng.metrics)
         i = 0
         fold = eng.fold_window()
         n_binds = 0
@@ -291,3 +313,4 @@ def closed(driver: Driver, horizon: float, seconds: float, window: Window,
             if returned <= w_end:
                 window.binds_in_window += len(episode.order) - n_binds
             n_binds = len(episode.order)
+        window.add_counters(eng, before)

@@ -41,6 +41,13 @@ def padded_nodes(num_nodes: int) -> int:
     return -(-num_nodes // LANE) * LANE
 
 
+def kernel_nodes(num_nodes: int, clusters: int = 1) -> int:
+    """Padded nodes one call walks.  A federation's call walks the tiles
+    of all ``clusters`` clusters, each padded alike to the blocks of the
+    largest, which holds ``ceil(num_nodes / clusters)`` nodes."""
+    return clusters * padded_nodes(-(-num_nodes // clusters))
+
+
 def ops(rows: int, nodes: int) -> int:
     """Element-wise operations of one call at ``rows`` (padded) rows."""
     n = padded_nodes(nodes)

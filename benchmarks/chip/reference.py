@@ -22,7 +22,17 @@ the deployments this benchmark runs:
   lowest index on ties;
 * the cluster's books: a float64 ledger of quotas per node, the float32
   residual each decision reads (debited by each grant, re-derived from
-  the ledger on each release), and no node above its allocatable.
+  the ledger on each release), and no node above its allocatable;
+* a federation of ``num_clusters`` K clusters pooled behind one
+  scheduler: K contiguous clusters partition the node table in order,
+  as even as possible, the first clusters taking the remainder, and
+  node ids are global.  Placement is worst fit over every node of the
+  federation (lowest global id on ties), and ARAS's total residual is
+  the federation's.  The program sums it per cluster and folds; the
+  reference sums in its own order, which the quota gap allows for (the
+  same float32 reassociation as within one cluster).  ``sharding`` only
+  lays the clusters out over devices and is not read.  At K = 1 this is
+  the single cluster above.
 
 ``Reference.follow`` checks a program's decisions one by one.  At every
 request the reference decides from its own state and compares the
@@ -125,8 +135,6 @@ class Reference:
             raise ValueError("the reference states worst-fit placement only")
         if float(timing.get("batch_window", 0.0)) != 0.0:
             raise ValueError("the reference states a zero fold window only")
-        if int(cluster.get("num_clusters", 1)) != 1:
-            raise ValueError("the reference states one cluster only")
         if config.get("faults", {}).get("schedule", "none") != "none" \
                 or config.get("forecast", {}).get("enabled") \
                 or config.get("vertical", {}).get("enabled"):
@@ -135,6 +143,9 @@ class Reference:
         self.dt = _dtype(dtype)
         self.lowp = dtype != "float32"
         m = int(cluster["num_nodes"])
+        k = int(cluster.get("num_clusters", 1))
+        if not 1 <= k <= m:
+            raise ValueError(f"{k} clusters cannot partition {m} nodes")
         self.cap_cpu = np.full(m, float(cluster["node_cpu"]))
         self.cap_mem = np.full(m, float(cluster["node_mem"]))
         self.used_cpu = np.zeros(m)

@@ -350,6 +350,8 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
             for d, kind, t, rows in sorted(window.slowest, reverse=True)))
     log(f"dispatches in the window: {window.dispatches}, rows "
         f"{sum(window.dispatch_rows)}, steps {window.steps}")
+    log("counters in the window: " + ", ".join(
+        f"{k} {v}" for k, v in sorted(window.counters.items())))
     metrics: Dict[str, dict] = {}
     breakdown = None
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -373,9 +375,10 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
                                       "unit": m["unit"]}
     else:
         from _common import Context
+        import kernel_cost
         import peaks as peak_table
 
-        red = tracing.Reduction(events)
+        red = window.trace = tracing.Reduction(events)
         try:
             chip = peak_table.peaks(devices[0].device_kind)
         except KeyError:
@@ -383,8 +386,10 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
                 raise
             chip = None
         ctx = Context(trace=red, dispatch_rows=window.dispatch_rows,
-                      nodes=int(engine["cluster"]["num_nodes"])
-                      // int(engine["cluster"]["num_clusters"]),
+                      counters=window.counters,
+                      nodes=kernel_cost.kernel_nodes(
+                          int(engine["cluster"]["num_nodes"]),
+                          int(engine["cluster"]["num_clusters"])),
                       engine=engine, peaks=chip)
         for m in cell["per_layer"]:
             value = reader(m["name"])(ctx)
@@ -401,6 +406,9 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool,
                      "idle_gaps": red.idle_gaps(0)}
         log(f"kernel events {len(red.kernel_events(0))}, fused steps "
             f"{len(red.fused_steps(0))}, step spans {len(red.steps)}")
+        log("program spans: " + ", ".join(
+            f"{name} {red.count(name)}"
+            for name in sorted({sp[0] for sp in red.program})))
 
     limits = cell["config_file"]["check_limits"]
     checks = {

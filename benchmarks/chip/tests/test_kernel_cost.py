@@ -14,6 +14,14 @@ def test_count_at_one_shape_by_hand():
     assert kernel_cost.hbm_bytes(8, 200) == 4096 + 512 + 416 + 8 == 5032
 
 
+@pytest.mark.parametrize("num_nodes,clusters,walked", [
+    (5000, 1, 5120), (20000, 4, 20480), (256, 4, 512), (10, 3, 384)])
+def test_a_federation_call_walks_every_clusters_tiles(num_nodes, clusters,
+                                                       walked):
+    # Each cluster padded to the blocks of the largest (ceil(m / K)).
+    assert kernel_cost.kernel_nodes(num_nodes, clusters) == walked
+
+
 def test_bound_is_bandwidth_at_the_cell_shapes():
     chip = peaks.peaks("TPU v5 lite")
     for rows in (1, 8, 1024, 2048):
