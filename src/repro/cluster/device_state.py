@@ -41,6 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.cluster import federation
 from repro.cluster.federation import LANE, FederatedLayout
@@ -141,23 +142,33 @@ class DeviceResidualState:
     def create(residual_cpu, residual_mem, cap_cpu, cap_mem,
                layout: Optional[FederatedLayout],
                res_pad: float) -> "DeviceResidualState":
-        """Stage the host caches once; afterwards only deltas move."""
-        res_c = jnp.asarray(residual_cpu, jnp.float32)
-        res_m = jnp.asarray(residual_mem, jnp.float32)
-        num_nodes = int(res_c.shape[0])
-        rc2 = federation.pad_tiles_federated(res_c, layout, res_pad)
-        rm2 = federation.pad_tiles_federated(res_m, layout, res_pad)
-        cc2 = federation.pad_tiles_federated(
-            jnp.asarray(cap_cpu, jnp.float32), layout, 0.0)
-        cm2 = federation.pad_tiles_federated(
-            jnp.asarray(cap_mem, jnp.float32), layout, 0.0)
-        mask2 = jnp.asarray(federation.tile_mask(num_nodes, layout))
-        return DeviceResidualState(
-            layout=layout, num_nodes=num_nodes, res_pad=res_pad,
-            rc2=rc2, rm2=rm2, cc2=cc2, cm2=cm2, mask2=mask2,
-            bsum_c=federation.tile_block_sums(rc2, mask2),
-            bsum_m=federation.tile_block_sums(rm2, mask2),
-        )
+        """Stage the host caches once; afterwards only deltas move.
+
+        The tiles are laid out on the host and each copied to the device
+        once (:attr:`staged_bytes`), inside an ``alloc.state`` span.
+        """
+        num_nodes = int(np.shape(residual_cpu)[0])
+        with TraceAnnotation("alloc.state", nodes=num_nodes) as span:
+            host = [federation.host_tiles(residual_cpu, layout, res_pad),
+                    federation.host_tiles(residual_mem, layout, res_pad),
+                    federation.host_tiles(cap_cpu, layout, 0.0),
+                    federation.host_tiles(cap_mem, layout, 0.0),
+                    federation.tile_mask(num_nodes, layout)]
+            span.set_metadata(bytes=sum(a.nbytes for a in host))
+            rc2, rm2, cc2, cm2, mask2 = (jnp.asarray(a) for a in host)
+            return DeviceResidualState(
+                layout=layout, num_nodes=num_nodes, res_pad=res_pad,
+                rc2=rc2, rm2=rm2, cc2=cc2, cm2=cm2, mask2=mask2,
+                bsum_c=federation.tile_block_sums(rc2, mask2),
+                bsum_m=federation.tile_block_sums(rm2, mask2),
+            )
+
+    @property
+    def staged_bytes(self) -> int:
+        """Bytes :meth:`create` copied host to device: the four tiles
+        and the mask."""
+        return sum(int(a.nbytes) for a in
+                   (self.rc2, self.rm2, self.cc2, self.cm2, self.mask2))
 
     def apply_updates(self, nodes: np.ndarray, res_cpu: np.ndarray,
                       res_mem: np.ndarray) -> "DeviceResidualState":

@@ -16,7 +16,9 @@ transform of the existing burst pipeline:
   maxima.
 * ``pad_tiles_federated`` — flat ``[m]`` node arrays → federated tiles
   (single-cluster layouts delegate to the legacy ``pad_tiles``, so the
-  ``num_clusters=1`` path is bit-for-bit the existing allocator).
+  ``num_clusters=1`` path is bit-for-bit the existing allocator);
+  ``host_tiles`` lays out the same tiles in NumPy, for the one upload
+  that builds the device-resident state.
 * ``shard_totals`` — per-shard residual totals ``[K]``; the sequential
   core debits only the accepting shard's entry (O(1), like the legacy
   scalar totals) and re-derives the federation-wide total by a static
@@ -164,6 +166,19 @@ def pad_tiles_federated(
     gathered = jnp.where(perm >= 0, arr[jnp.clip(perm, 0)],
                          jnp.asarray(pad_value, arr.dtype))
     return gathered.reshape(layout.num_blocks, LANE)
+
+
+def host_tiles(arr, layout: Optional[FederatedLayout],
+               pad_value: float) -> np.ndarray:
+    """:func:`pad_tiles_federated` in NumPy, on the host: the same
+    float32 tiles, laid out before their one copy to the device."""
+    arr = np.asarray(arr, np.float32)
+    layout = layout or FederatedLayout.single(arr.shape[0])
+    out = np.full((layout.num_clusters, layout.nb_per * LANE), pad_value,
+                  np.float32)
+    for k, (m, off) in enumerate(zip(layout.node_counts, layout.offsets)):
+        out[k, :m] = arr[off: off + m]
+    return out.reshape(layout.num_blocks, LANE)
 
 
 def shard_totals(arr: jax.Array, layout: Optional[FederatedLayout]):

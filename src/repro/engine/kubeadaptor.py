@@ -72,8 +72,10 @@ debits and record stamps track the engine's host-side state transitions.
 The phases of a step are ``jax.profiler.TraceAnnotation`` spans —
 ``engine.inject``, ``engine.fold``, ``engine.stage`` and
 ``engine.apply``, around the allocator's ``alloc.pack``,
-``alloc.launch`` and ``alloc.wait`` — recorded only while a profiler
-session runs (README, "Tracing the scheduler").
+``alloc.launch`` and ``alloc.wait``, and ``alloc.state`` inside
+``engine.stage`` where an engine's first dispatch builds the
+device-resident state — recorded only while a profiler session runs
+(README, "Tracing the scheduler").
 """
 from __future__ import annotations
 
@@ -162,10 +164,14 @@ class EngineMetrics:
     num_dispatches: int = 0
     dispatched_rows: int = 0
     # Bytes the batched paths copied host→device to issue those
-    # dispatches (``PendingBurst.staged_bytes``; the one-time upload of
-    # the cluster when the device state is created, and the per-row
-    # replay, are not counted).
+    # dispatches (``PendingBurst.staged_bytes``; the per-row replay is
+    # not counted).
     staged_bytes: int = 0
+    # Device-resident states built (one per engine, at its first
+    # batched dispatch) and the bytes each copied host to device: the
+    # whole cluster's tiles (``DeviceResidualState.staged_bytes``).
+    state_builds: int = 0
+    state_bytes: int = 0
     # Bytes those dispatches copied device→host: one packed decision
     # buffer each (``PendingBurst.fetched_bytes``).
     fetched_bytes: int = 0
@@ -487,6 +493,8 @@ class KubeAdaptor:
             cap_cpu, cap_mem = self.cluster.capacity_view()
             self._state = self.allocator.create_state(
                 res_cpu, res_mem, cap_cpu, cap_mem)
+            self.metrics.state_builds += 1
+            self.metrics.state_bytes += self._state.staged_bytes
             self.cluster.track_dirty()
             return self._state, None
         return self._state, self.cluster.drain_dirty()

@@ -47,7 +47,7 @@ RES_PAD = -1e30
 
 
 def _fold_sum(vec: jax.Array) -> jax.Array:
-    """Static left-fold sum of a tiny [K] vector — exact order.
+    """Static left-fold sum of a [K] vector (K up to 100) — exact order.
 
     The federated core and the Pallas kernel must agree bit-for-bit on
     the federation-wide total, so both reduce the per-shard totals in

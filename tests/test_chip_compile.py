@@ -75,13 +75,13 @@ def no_persistent_cache():
 
 
 def _kernel_args(sharding, rows: int, mode: str, clusters: int = 1,
-                 flag=jnp.int32):
-    """Shapes of one sequential-core call at ``NODES`` nodes (the kernel
+                 flag=jnp.int32, nodes: int = NODES):
+    """Shapes of one sequential-core call at ``nodes`` nodes (the kernel
     takes int32 row flags, the engine's dispatch bools)."""
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    nb = FederatedLayout.split(NODES, clusters).num_blocks
+    nb = FederatedLayout.split(nodes, clusters).num_blocks
     tiles = spec((nb, LANE))
     total = spec(()) if clusters == 1 else spec((clusters,))
     row, flag = spec((rows,)), spec((rows,), flag)
@@ -129,10 +129,18 @@ def test_alloc_scan_compiles_for_v5e(one_chip, mode, policy, rows):
     assert _has_kernel(compiled)
 
 
-def test_federated_totals_kernel_compiles_for_v5e(one_chip):
-    """K=4 per-shard totals in SMEM, tiles cluster-major on one chip."""
+@pytest.mark.parametrize("clusters,nodes,rows", [
+    (4, NODES, 256),
+    # The federation cell: 512,000 padded nodes, whose tiles need more
+    # than the default scoped VMEM limit, and 100 unrolled shard loops.
+    (100, 100 * NODES, 1024),
+])
+def test_federated_totals_kernel_compiles_for_v5e(one_chip, clusters, nodes,
+                                                  rows):
+    """K per-shard totals in SMEM, tiles cluster-major on one chip."""
     compiled = alloc_scan_pallas.lower(
-        *_kernel_args(one_chip, 256, "aras", clusters=4),
+        *_kernel_args(one_chip, rows, "aras", clusters=clusters,
+                      nodes=nodes),
         alpha=DEFAULT_ALPHA, beta=DEFAULT_BETA, policy="worst_fit",
         mode="aras", interpret=False,
     ).compile()

@@ -182,12 +182,16 @@ def test_federated_fcfs_any_values():
         _assert_batch_equal(single, fed, seed)
 
 
+@pytest.mark.parametrize("counts", [(4, 3, 2), (1,) * 100],
+                         ids=["K3", "K100"])
 @pytest.mark.parametrize("mode_cls", ALLOCATORS)
 @pytest.mark.parametrize("policy", PLACEMENT_POLICIES)
-def test_federated_scan_matches_pallas(mode_cls, policy):
-    """Both sequential-core backends agree bit-for-bit at K > 1."""
-    case = _case(1, m=9, integral=False)
-    lay = FederatedLayout((4, 3, 2))
+def test_federated_scan_matches_pallas(mode_cls, policy, counts):
+    """Both sequential-core backends agree bit-for-bit at K > 1, up to
+    the hundred clusters of the federation cell (one node each: the
+    shard loops, not the tiles, are what K = 100 exercises)."""
+    case = _case(1, m=sum(counts), integral=False)
+    lay = FederatedLayout(counts)
     ref = _decide(mode_cls, lay, case, policy, backend="scan")
     ker = _decide(mode_cls, lay, case, policy, backend="pallas")
     _assert_batch_equal(ref, ker, (mode_cls.__name__, policy))

@@ -15,13 +15,14 @@ input state (its tile buffers are donated to the fused dispatch), so
 every chain below threads ``state = pending.state`` and never touches a
 state it already passed in.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.api import AllocatorConfig, TimingConfig
-from repro.cluster import device_state
+from repro.cluster import device_state, federation
 from repro.cluster.device_state import DeviceResidualState
-from repro.cluster.federation import FederatedLayout
+from repro.cluster.federation import LANE, FederatedLayout
 from repro.core.allocator import RES_PAD, make_allocator
 from repro.core.types import TaskBatch, TaskWindow
 from repro.engine import EngineConfig, KubeAdaptor
@@ -101,6 +102,27 @@ def test_apply_updates_matches_recreate(k):
             assert np.array_equal(np.asarray(getattr(state, field)),
                                   np.asarray(getattr(fresh, field))), \
                 (field, trial)
+
+
+@pytest.mark.parametrize("k", [1, 4, N_NODES])
+def test_create_lays_out_the_repad_tiles(k):
+    """``create`` lays the tiles out on the host and copies each once:
+    they equal, element for element, what the re-pad path pads on the
+    device, and ``staged_bytes`` is four float32 tiles and the mask."""
+    rng = np.random.default_rng(3 + k)
+    res_cpu, res_mem, cap_cpu, cap_mem = _cluster_arrays(rng)
+    lay = _layout(k)
+    state = DeviceResidualState.create(
+        res_cpu, res_mem, cap_cpu, cap_mem, lay, RES_PAD)
+    for field, arr, pad in (("rc2", res_cpu, RES_PAD),
+                            ("rm2", res_mem, RES_PAD),
+                            ("cc2", cap_cpu, 0.0), ("cm2", cap_mem, 0.0)):
+        want = federation.pad_tiles_federated(jnp.asarray(arr), lay, pad)
+        assert np.array_equal(np.asarray(getattr(state, field)),
+                              np.asarray(want)), field
+    nb = lay.num_blocks if lay else -(-N_NODES // LANE)
+    assert state.rc2.shape == (nb, LANE)
+    assert state.staged_bytes == nb * LANE * (4 * 4 + 1)
 
 
 def test_apply_updates_empty_is_noop():

@@ -8,7 +8,10 @@ spans carry the engine's dispatch index once each and in order,
 injection nests only where it may, ``EngineMetrics.staged_bytes`` equals
 a count made from the allocator's inputs alone, ``EngineMetrics.
 fetched_bytes`` equals the bytes of the ``alloc.wait`` spans (one packed
-``int32[6, pow2(B)]`` per dispatch), and tracing changes no decision.
+``int32[6, pow2(B)]`` per dispatch), ``alloc.state`` opens once per
+device-state build with ``EngineMetrics.state_builds`` and
+``state_bytes`` counting the builds and their bytes, and tracing changes
+no decision.
 """
 import glob
 import os
@@ -30,6 +33,8 @@ pytestmark = pytest.mark.tier1
 N_NODES = 64
 SPANS = ("engine.inject", "engine.fold", "engine.stage", "alloc.pack",
          "alloc.launch", "alloc.wait", "engine.apply")
+# The device-resident state's build: once per engine, on that path only.
+STATE = "alloc.state"
 # The spans of one dispatch, in the order they open.
 PER_DISPATCH = ("engine.stage", "alloc.pack", "alloc.launch", "alloc.wait",
                 "engine.apply")
@@ -121,18 +126,13 @@ class Served(NamedTuple):
     spans: list  # (name, start_ns, end_ns, metadata)
 
 
-def _serve(incremental: bool, trace_dir=None) -> Served:
-    eng = _engine(incremental)
-    expected: list = []
-    fetched: list = []
-    _count_staging(eng, expected, fetched)
-    if trace_dir is None:
-        metrics = serve_stream(eng, _arrivals()).metrics
-        return Served(incremental, metrics, expected, fetched, [])
+def _traced(trace_dir, serve):
+    """Run ``serve()`` under the profiler; its result and the program's
+    spans, as ``(name, start_ns, end_ns, metadata)``."""
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     with jax.profiler.trace(str(trace_dir), profiler_options=opts):
-        metrics = serve_stream(eng, _arrivals()).metrics
+        out = serve()
     path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
                       recursive=True)
     spans = []
@@ -141,10 +141,23 @@ def _serve(incremental: bool, trace_dir=None) -> Served:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name in SPANS:
+                if ev.name in SPANS or ev.name == STATE:
                     start = int(ev.start_ns)
                     spans.append((ev.name, start, start + int(ev.duration_ns),
                                   dict(ev.stats)))
+    return out, spans
+
+
+def _serve(incremental: bool, trace_dir=None) -> Served:
+    eng = _engine(incremental)
+    expected: list = []
+    fetched: list = []
+    _count_staging(eng, expected, fetched)
+    if trace_dir is None:
+        metrics = serve_stream(eng, _arrivals()).metrics
+        return Served(incremental, metrics, expected, fetched, [])
+    metrics, spans = _traced(
+        trace_dir, lambda: serve_stream(eng, _arrivals()).metrics)
     return Served(incremental, metrics, expected, fetched, spans)
 
 
@@ -160,7 +173,8 @@ def _named(spans, name):
 
 def test_every_span_is_recorded(traced):
     spans = traced.spans
-    assert {s[0] for s in spans} == set(SPANS)
+    state = {STATE} if traced.incremental else set()
+    assert {s[0] for s in spans} == set(SPANS) | state
 
 
 @pytest.mark.parametrize("name", PER_DISPATCH)
@@ -228,3 +242,48 @@ def test_tracing_changes_no_decision(traced):
     assert on.staged_bytes == off.staged_bytes
     assert on.fetched_bytes == off.fetched_bytes
     assert on.makespan == off.makespan
+
+
+def test_state_build_opens_in_the_first_stage(traced):
+    """The device-resident state is built once, inside the first
+    dispatch's ``engine.stage``, and counted: ``state_builds`` spans,
+    ``state_bytes`` their bytes, four float32 tiles and the mask.  The
+    re-pad path builds none."""
+    metrics, builds = traced.metrics, _named(traced.spans, STATE)
+    if not traced.incremental:
+        assert builds == []
+        assert (metrics.state_builds, metrics.state_bytes) == (0, 0)
+        return
+    (_, start, end, stats), = builds
+    first, = [s for s in _named(traced.spans, "engine.stage")
+              if s[3]["dispatch"] == 0]
+    assert first[1] <= start and end <= first[2]
+    assert stats["nodes"] == N_NODES
+    nb = -(-N_NODES // 128)
+    assert metrics.state_builds == 1
+    assert metrics.state_bytes == stats["bytes"] == nb * 128 * (4 * 4 + 1)
+
+
+@pytest.mark.parametrize("clusters", [1, 4])
+def test_one_state_span_per_build(tmp_path, clusters):
+    """Three fresh engines build three states, each under its own
+    ``alloc.state`` span, and the counters sum what each uploaded: a
+    federation's tiles hold every cluster, each padded to whole
+    128-lane blocks."""
+    nodes = 200  # 4 clusters of 50 nodes: one block each, 4 in all
+    cfg = _engine(True).cfg.evolve(num_nodes=nodes, num_clusters=clusters)
+
+    def serve():
+        out = []
+        for _ in range(3):
+            out.append(serve_stream(KubeAdaptor(cfg), _arrivals(6)).metrics)
+        return out
+
+    metrics, spans = _traced(tmp_path, serve)
+    builds = _named(spans, STATE)
+    blocks = 2 if clusters == 1 else clusters
+    per_build = blocks * 128 * (4 * 4 + 1)
+    assert [s[3]["bytes"] for s in builds] == [per_build] * 3
+    assert [s[3]["nodes"] for s in builds] == [nodes] * 3
+    assert [m.state_builds for m in metrics] == [1, 1, 1]
+    assert [m.state_bytes for m in metrics] == [per_build] * 3
