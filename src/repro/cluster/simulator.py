@@ -23,6 +23,12 @@ identical: both see residuals produced by the same sequence of float32
 debits.  Pod capacity grows in powers of two so Informer consumers keep
 stable JIT shapes (free slots are ``active=False`` and numerically inert).
 
+A fused dispatch's binds may defer their node books (``defer_books`` /
+``flush_books``): each ``bind`` then does only the per-pod work and the
+dispatch's node, quota and slot writes land in one array update, in bind
+order, so the books come out bit for bit as row-at-a-time binds leave
+them.
+
 Invariant (checked): at every instant, Σ quotas of consuming pods on a
 node ≤ the node's allocatable capacity.
 """
@@ -30,7 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Dict, List
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -115,6 +121,9 @@ class ClusterSim:
         # chaos is configured.
         self._offline = np.zeros((num_nodes,), bool)
         self._num_offline = 0
+        # Binds whose node books wait for ``flush_books``, as
+        # ``(node, cpu, mem, slot)``; None while books are written at once.
+        self._deferred: Optional[List[Tuple[int, float, float, int]]] = None
 
     # ------------------------------------------------------------- plumbing
     def _grow(self) -> None:
@@ -138,46 +147,105 @@ class ClusterSim:
 
     def bind(self, task: TaskSpec, alloc: Allocation, now: float,
              workflow_id: str = "") -> Pod:
-        """Create a pod with the allocated quota on the chosen node."""
-        i = alloc.node
+        """Create a pod with the allocated quota on the chosen node.
+
+        Between ``defer_books`` and ``flush_books`` the node's books and
+        the pod's slot arrays are queued instead of written; the cluster
+        totals, the slot and the ``Pod`` are made here either way.
+        """
+        i, cpu, mem = alloc.node, alloc.cpu, alloc.mem
         if self._num_offline and self._offline[i]:
             raise RuntimeError(
                 f"bind on offline node {i}: the allocator placed "
-                f"quota=({alloc.cpu}, {alloc.mem}) on a cordoned node "
+                f"quota=({cpu}, {mem}) on a cordoned node "
                 f"whose residuals should read zero"
             )
-        if (self._used_cpu[i] + alloc.cpu
-                > self._alloc_cpu[i] + self._OVERCOMMIT_EPS
-                or self._used_mem[i] + alloc.mem
-                > self._alloc_mem[i] + self._OVERCOMMIT_EPS):
-            raise RuntimeError(
-                f"overcommit on node {i}: "
-                f"used=({self._used_cpu[i]}, {self._used_mem[i]}) "
-                f"quota=({alloc.cpu}, {alloc.mem}) "
-                f"cap=({self._alloc_cpu[i]}, {self._alloc_mem[i]})"
-            )
-        self._used_cpu[i] += alloc.cpu
-        self._used_mem[i] += alloc.mem
-        self._used_cpu_total += alloc.cpu
-        self._used_mem_total += alloc.mem
-        self._res_cpu32[i] -= np.float32(alloc.cpu)
-        self._res_mem32[i] -= np.float32(alloc.mem)
-        if self._track_dirty:
-            self._dirty.append(i)
+        deferred = self._deferred
+        if deferred is None:
+            if (self._used_cpu[i] + cpu
+                    > self._alloc_cpu[i] + self._OVERCOMMIT_EPS
+                    or self._used_mem[i] + mem
+                    > self._alloc_mem[i] + self._OVERCOMMIT_EPS):
+                raise RuntimeError(
+                    f"overcommit on node {i}: "
+                    f"used=({self._used_cpu[i]}, {self._used_mem[i]}) "
+                    f"quota=({cpu}, {mem}) "
+                    f"cap=({self._alloc_cpu[i]}, {self._alloc_mem[i]})"
+                )
+            self._used_cpu[i] += cpu
+            self._used_mem[i] += mem
+            self._res_cpu32[i] -= np.float32(cpu)
+            self._res_mem32[i] -= np.float32(mem)
+            if self._track_dirty:
+                self._dirty.append(i)
+        self._used_cpu_total += cpu
+        self._used_mem_total += mem
         if not self._free_slots:
             self._grow()
         slot = self._free_slots.pop()
-        self._pod_node[slot] = i
-        self._pod_cpu[slot] = alloc.cpu
-        self._pod_mem[slot] = alloc.mem
-        self._pod_active[slot] = True
+        if deferred is None:
+            self._pod_node[slot] = i
+            self._pod_cpu[slot] = cpu
+            self._pod_mem[slot] = mem
+            self._pod_active[slot] = True
+        else:
+            deferred.append((i, cpu, mem, slot))
         pod = Pod(
-            uid=next(self._uid), task=task, quota=Resources(alloc.cpu, alloc.mem),
+            uid=next(self._uid), task=task, quota=Resources(cpu, mem),
             node=i, phase=PodPhase.RUNNING, t_created=now, t_started=now,
             workflow_id=workflow_id, slot=slot,
         )
         self.pods[pod.uid] = pod
         return pod
+
+    def defer_books(self) -> None:
+        """Queue the node books of the binds that follow until
+        :meth:`flush_books`.  Nothing may read the per-node arrays in
+        between: ``residual_view`` and ``drain_dirty`` refuse to."""
+        assert self._deferred is None, "node books already deferred"
+        self._deferred = []
+
+    def flush_books(self) -> int:
+        """Write the deferred binds' books in one update; returns how many.
+
+        ``ufunc.at`` adds in bind order (a fancy-index ``+=`` would drop
+        repeated nodes), so the float64 books and the float32 residuals
+        read bit for bit what row-at-a-time binds leave.  Quotas are
+        never negative, so a node's final books exceed its capacity iff
+        some bind on it did, and one test over the touched nodes raises
+        where row-at-a-time binds would have.
+        """
+        deferred, self._deferred = self._deferred, None
+        if not deferred:
+            return 0
+        nodes, cpu, mem, slots = zip(*deferred)
+        idx = np.array(nodes, np.int64)
+        cpu64 = np.array(cpu, np.float64)
+        mem64 = np.array(mem, np.float64)
+        np.add.at(self._used_cpu, idx, cpu64)
+        np.add.at(self._used_mem, idx, mem64)
+        np.subtract.at(self._res_cpu32, idx, cpu64.astype(np.float32))
+        np.subtract.at(self._res_mem32, idx, mem64.astype(np.float32))
+        if self._track_dirty:
+            self._dirty.extend(nodes)
+        at = np.array(slots, np.int64)
+        self._pod_node[at] = idx
+        self._pod_cpu[at] = cpu64
+        self._pod_mem[at] = mem64
+        self._pod_active[at] = True
+        touched = np.unique(idx)
+        eps = self._OVERCOMMIT_EPS
+        over = touched[
+            (self._used_cpu[touched] > self._alloc_cpu[touched] + eps)
+            | (self._used_mem[touched] > self._alloc_mem[touched] + eps)]
+        if over.size:
+            i = int(over[0])
+            raise RuntimeError(
+                f"overcommit on node {i}: a dispatch's binds took it to "
+                f"used=({self._used_cpu[i]}, {self._used_mem[i]}) "
+                f"cap=({self._alloc_cpu[i]}, {self._alloc_mem[i]})"
+            )
+        return len(deferred)
 
     def finish(self, uid: int, now: float, phase: PodPhase) -> Pod:
         """Transition a Running pod to a terminal phase, releasing quota."""
@@ -370,6 +438,7 @@ class ClusterSim:
         scattering them into device tiles reproduces ``residual_view``
         exactly for those rows.
         """
+        assert self._deferred is None, "node books deferred"
         if not self._dirty:
             return (np.zeros((0,), np.int64), np.zeros((0,), np.float32),
                     np.zeros((0,), np.float32))
@@ -422,6 +491,7 @@ class ClusterSim:
         read-only); identical to what Alg. 2 would recompute, without the
         O(pods) pass.
         """
+        assert self._deferred is None, "node books deferred"
         return self._res_cpu32, self._res_mem32
 
     def capacity_view(self):

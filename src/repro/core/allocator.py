@@ -770,6 +770,24 @@ def allocation_at(result: BatchAllocation, i: int) -> Allocation:
     )
 
 
+def decoded_rows(result: BatchAllocation):
+    """Yield ``(feasible, attempted, alloc)`` per row of a batch result.
+
+    The arrays are turned into Python values once for the whole result
+    (``tolist`` gives the floats ``float(np.float32)`` gives); ``alloc``
+    is the row's ``Allocation`` where it is feasible, else None.
+    """
+    names = [SCENARIO_NAMES[s] for s in result.scenario.tolist()]
+    for feasible, attempted, cpu, mem, node, scenario in zip(
+            result.feasible.tolist(), result.attempted.tolist(),
+            result.cpu.tolist(), result.mem.tolist(), result.node.tolist(),
+            names):
+        # Positional: a third cheaper than keywords, once per bound row.
+        alloc = (Allocation(cpu, mem, node, True, scenario) if feasible
+                 else None)
+        yield feasible, attempted, alloc
+
+
 @dataclasses.dataclass
 class AdaptiveAllocator:
     """ARAS — Algorithm 1, burst-at-a-time.

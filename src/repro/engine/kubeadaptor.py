@@ -98,7 +98,7 @@ from repro.api.config import (
 from repro.api.registry import ALLOCATORS
 from repro.cluster import federation
 from repro.cluster.simulator import ClusterSim
-from repro.core.allocator import allocation_at
+from repro.core.allocator import decoded_rows
 from repro.core.types import (
     Allocation,
     BatchAllocation,
@@ -122,6 +122,12 @@ __all__ = [
     "FaultConfig", "KubeAdaptor", "TimingConfig", "VerticalConfig",
     "WorkflowRun", "run_experiment",
 ]
+
+# A batched dispatch of at least this many rows writes its binds' node
+# books in one array update (``ClusterSim.flush_books``); below it the
+# update's fixed cost (about 16 us on a CPU core, against about 4 us a
+# row written at once) outweighs what it saves.
+DEFERRED_BOOKS_MIN_ROWS = 8
 
 
 @dataclasses.dataclass
@@ -175,6 +181,10 @@ class EngineMetrics:
     # Bytes those dispatches copied device→host: one packed decision
     # buffer each (``PendingBurst.fetched_bytes``).
     fetched_bytes: int = 0
+    # Binds whose node books the per-dispatch array update wrote
+    # (``ClusterSim.flush_books``): every bind of a batched dispatch,
+    # none of the per-row replay's.
+    batched_binds: int = 0
     # SLA accounting (paper Eqs. 2-4): per-workflow deadline violations
     sla_violations: List[Tuple[str, float, float]] = dataclasses.field(
         default_factory=list  # (workflow, finished_at, deadline)
@@ -505,8 +515,7 @@ class KubeAdaptor:
 
         Monitor reads the incremental caches (no snapshot rebuild);
         Analyse/Plan run inside the allocator's single dispatch; Execute
-        happens in ``_allocate_group``/``_bind`` from the one synced
-        result.
+        happens in ``_allocate_group`` from the one synced result.
 
         On the device-state path the dispatch is issued asynchronously
         against the incrementally-maintained tiles; while the device
@@ -560,46 +569,85 @@ class KubeAdaptor:
             alloc, attempted = replay.step(i, res_cpu, res_mem)
             yield alloc.feasible, attempted, alloc
 
-    def _bind(self, wf_id: str, task: TaskSpec, alloc: Allocation) -> None:
-        """Execute phase: Containerized Executor creates the pod."""
-        key = f"{wf_id}/{task.task_id}"
-        pod = self.cluster.bind(task, alloc, self._now, workflow_id=wf_id)
-        self.store.mark_started(key, self._now)
-        if self._displaced_at:
-            t0 = self._displaced_at.pop(key, None)
-            if t0 is not None:  # a displaced task recovered (re-bound)
-                self.metrics.recovery_times.append((key, self._now - t0))
-        if self._attempts:
-            # A successful bind resets the task's retry budget.
-            self._attempts.pop(key, None)
-        run = self.runs[wf_id]
-        if run.first_start is None:
-            run.first_start = self._now
-        self.metrics.num_allocations += 1
-        self.metrics.alloc_trace.append(
-            (self._now, key, alloc.cpu, alloc.mem, alloc.scenario)
-        )
-        # Will this quota OOM? (§6.2.2: runtime memory floor + β)
+    def _binder(self) -> Callable[[str, TaskSpec, Allocation], None]:
+        """Execute phase for one dispatch's rows: the Containerized
+        Executor creates each pod and schedules its end.
+
+        Returns ``bind(wf_id, task, alloc)``.  What no bind of the
+        dispatch can change is read once here: the clock, the timing and
+        beta, whether any displaced task waits for its recovery, and
+        whether retry budgets are kept (without ``max_retries`` the
+        attempt books stay as they are through the dispatch).  Each bind
+        still goes through ``self.cluster.bind``, once per row, in
+        admission order.
+        """
+        cluster, store, metrics, runs = (self.cluster, self.store,
+                                         self.metrics, self.runs)
+        now = self._now
         timing = self.cfg.timing
-        runtime_floor = task.runtime_min_mem() + self.cfg.alloc.beta
-        wall = timing.duration_multiplier * task.duration
-        if alloc.mem < runtime_floor - 1e-9 and task.mem > 0:
-            t_oom = self._now + timing.pod_startup_delay + \
-                timing.oom_fraction * wall
-            self._push(t_oom, EventKind.OOM, (pod.uid, wf_id))
-        else:
-            t_done = self._now + timing.pod_startup_delay + wall
-            self._push(t_done, EventKind.COMPLETE, (pod.uid, wf_id))
-        if self._vertical and not self._resize_armed \
-                and task.usage_curve is not None:
-            # First usage-curve pod on an idle controller: arm the
-            # periodic sweep.  The controller re-arms itself while
-            # resizable pods remain and disarms (in ``step``) when none
-            # do, so a drained cluster queues no trailing RESIZE events.
-            self._resize_armed = True
-            self._push(self._now + self.cfg.vertical.check_interval,
-                       EventKind.RESIZE, ())
-        self._sample_usage()
+        startup, oom_fraction = timing.pod_startup_delay, timing.oom_fraction
+        multiplier = timing.duration_multiplier
+        beta = self.cfg.alloc.beta
+        displaced = self._displaced_at if self._displaced_at else None
+        attempts = (self._attempts if self._attempts
+                    or self._fault_cfg.max_retries is not None else None)
+        vertical = self._vertical
+        push = self.queue.push
+        trace = metrics.alloc_trace.append
+
+        def bind(wf_id: str, task: TaskSpec, alloc: Allocation) -> None:
+            key = f"{wf_id}/{task.task_id}"
+            pod = cluster.bind(task, alloc, now, workflow_id=wf_id)
+            store.mark_started(key, now)
+            if displaced is not None:
+                t0 = displaced.pop(key, None)
+                if t0 is not None:  # a displaced task recovered (re-bound)
+                    metrics.recovery_times.append((key, now - t0))
+            if attempts is not None:
+                # A successful bind resets the task's retry budget.
+                attempts.pop(key, None)
+            run = runs[wf_id]
+            if run.first_start is None:
+                run.first_start = now
+            metrics.num_allocations += 1
+            trace((now, key, alloc.cpu, alloc.mem, alloc.scenario))
+            # Will this quota OOM? (§6.2.2: runtime memory floor + β)
+            runtime_floor = task.runtime_min_mem() + beta
+            wall = multiplier * task.duration
+            if alloc.mem < runtime_floor - 1e-9 and task.mem > 0:
+                push(now + startup + oom_fraction * wall, EventKind.OOM,
+                     (pod.uid, wf_id))
+            else:
+                push(now + startup + wall, EventKind.COMPLETE,
+                     (pod.uid, wf_id))
+            if vertical and not self._resize_armed \
+                    and task.usage_curve is not None:
+                # First usage-curve pod on an idle controller: arm the
+                # periodic sweep.  The controller re-arms itself while
+                # resizable pods remain and disarms (in ``step``) when
+                # none do, so a drained cluster queues no trailing
+                # RESIZE events.
+                self._resize_armed = True
+                push(now + self.cfg.vertical.check_interval,
+                     EventKind.RESIZE, ())
+            self._sample_usage()
+
+        return bind
+
+    @contextlib.contextmanager
+    def _books(self, deferred: bool):
+        """Node books for one dispatch's binds.  ``deferred``: written in
+        one array update once the rows are applied (or a bind fails),
+        counted in ``batched_binds``; else at each bind, as the replay
+        needs, since it reads ``residual_view`` after every bind."""
+        if not deferred:
+            yield
+            return
+        self.cluster.defer_books()
+        try:
+            yield
+        finally:
+            self.metrics.batched_binds += self.cluster.flush_books()
 
     def _budget_exhausted(self, wf_id: str, task: TaskSpec) -> bool:
         """Count one attempted admission failure against the retry budget.
@@ -646,23 +694,24 @@ class KubeAdaptor:
         dying: Dict[str, None] = {}  # insertion-ordered workflow set
         bound_any = False
         waited_any = False
-        if self.cfg.alloc.batch_allocation:
+        batched = self.cfg.alloc.batch_allocation
+        if batched:
             # One fused dispatch decides every row before any applies.
             self.metrics.num_dispatches += 1
-            result = self._decide(entries, dispatch)
-            rows = ((bool(result.feasible[i]), bool(result.attempted[i]),
-                     allocation_at(result, i)) for i in range(len(entries)))
+            rows = decoded_rows(self._decide(entries, dispatch))
             applying = TraceAnnotation("engine.apply", dispatch=dispatch,
                                        rows=len(entries))
         else:
             self.metrics.num_dispatches += len(entries)
             rows = self._replay_rows(entries)
             applying = contextlib.nullcontext()
-        with applying:
+        bind = self._binder()
+        deferred = batched and len(entries) >= DEFERRED_BOOKS_MIN_ROWS
+        with applying, self._books(deferred):
             for (wf_id, task, origin), (feasible, attempted, alloc) in zip(
                     entries, rows):
                 if feasible:
-                    self._bind(wf_id, task, alloc)
+                    bind(wf_id, task, alloc)
                     bound_any = True
                 elif origin == "pending":
                     # Skipped rows (head-of-line) were never attempted and

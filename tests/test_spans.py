@@ -10,9 +10,11 @@ a count made from the allocator's inputs alone, ``EngineMetrics.
 fetched_bytes`` equals the bytes of the ``alloc.wait`` spans (one packed
 ``int32[6, pow2(B)]`` per dispatch), ``alloc.state`` opens once per
 device-state build with ``EngineMetrics.state_builds`` and
-``state_bytes`` counting the builds and their bytes, and tracing changes
-no decision.
+``state_bytes`` counting the builds and their bytes, ``engine.apply``
+opens once per dispatch around the array update that
+``EngineMetrics.batched_binds`` counts, and tracing changes no decision.
 """
+import dataclasses
 import glob
 import os
 from typing import NamedTuple
@@ -25,6 +27,7 @@ from jax.profiler import ProfileData
 
 from repro.api import ClusterConfig, EngineConfig, TimingConfig
 from repro.engine import KubeAdaptor
+from repro.engine.kubeadaptor import DEFERRED_BOOKS_MIN_ROWS
 from repro.serving import serve_stream
 from repro.workflows.spec import TaskSpec, WorkflowSpec
 
@@ -241,7 +244,23 @@ def test_tracing_changes_no_decision(traced):
     assert on.num_dispatches == off.num_dispatches
     assert on.staged_bytes == off.staged_bytes
     assert on.fetched_bytes == off.fetched_bytes
+    assert on.batched_binds == off.batched_binds
     assert on.makespan == off.makespan
+
+
+def test_batched_binds_is_an_integer_counter(traced):
+    """``batched_binds`` is an integer field of ``EngineMetrics``, where a
+    harness that reads every integer field finds it; the binds it counts
+    were made in dispatches whose ``engine.apply`` span has at least
+    ``DEFERRED_BOOKS_MIN_ROWS`` rows (this stream's dispatches are
+    narrower: ``tests/test_batched_apply.py`` serves wide ones)."""
+    metrics = traced.metrics
+    ints = {f.name for f in dataclasses.fields(metrics)
+            if type(getattr(metrics, f.name)) is int}
+    assert "batched_binds" in ints
+    wide = sum(s[3]["rows"] for s in _named(traced.spans, "engine.apply")
+               if s[3]["rows"] >= DEFERRED_BOOKS_MIN_ROWS)
+    assert metrics.batched_binds <= min(wide, metrics.num_allocations)
 
 
 def test_state_build_opens_in_the_first_stage(traced):
